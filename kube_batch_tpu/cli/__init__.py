@@ -74,4 +74,7 @@ def main(argv=None) -> None:
     opt = parse_options(argv)
     if opt.print_version:
         print_version_and_exit()
+    from ..utils.backend import enable_compile_cache
+
+    enable_compile_cache()
     run(opt)

@@ -1,7 +1,9 @@
 """ctypes wrapper for csrc/greedy.cpp (the reference loop baseline).
 
-Builds ``libgreedy.so`` with the packaged Makefile on first use (cached
-thereafter). The source lives INSIDE the package (``csrc/``) so installed
+Builds ``libgreedy.so`` with the packaged Makefile on first use, into a
+directory named by a hash of ``greedy.cpp`` and the Makefile, so a library
+is only ever loaded for the sources it was built from (file times decide
+nothing). The source lives INSIDE the package (``csrc/``) so installed
 wheels carry the native fallback, not just repo checkouts; when the
 package directory is read-only (site-packages), the build lands in a
 per-user cache directory instead. numpy in, numpy out; see greedy.cpp
@@ -11,7 +13,9 @@ for semantics.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import shutil
 import subprocess
 import tempfile
 from typing import Optional, Tuple
@@ -30,6 +34,33 @@ def _build_dirs():
         tempfile.gettempdir(), f"tpu-batch-native-{os.getuid()}", "build"
     )
 
+def _source_digest() -> str:
+    """Content hash of everything the library is built from."""
+    h = hashlib.blake2b(digest_size=8)
+    for name in ("greedy.cpp", "Makefile"):
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _build(out_dir: str) -> None:
+    """make into a private temp dir, then move the library into place
+    atomically: concurrent processes never load a half-written file."""
+    os.makedirs(out_dir, exist_ok=True)
+    tmp = tempfile.mkdtemp(dir=out_dir)
+    try:
+        subprocess.run(
+            ["make", "-B", "-C", _NATIVE_DIR, f"BUILD={tmp}"],
+            check=True, capture_output=True, text=True,
+        )
+        os.replace(
+            os.path.join(tmp, "libgreedy.so"),
+            os.path.join(out_dir, "libgreedy.so"),
+        )
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
+
+
 _lock = wrap_lock("native.loader")
 _lib: Optional[ctypes.CDLL] = None
 _load_error: Optional[str] = None
@@ -46,28 +77,18 @@ def _load() -> ctypes.CDLL:
             return _lib
         if _load_error is not None:
             raise NativeUnavailable(_load_error)
-        src = os.path.join(_NATIVE_DIR, "greedy.cpp")
         last_err = None
         lib = None
-        for build_dir in _build_dirs():
-            so_path = os.path.join(build_dir, "libgreedy.so")
+        try:
+            digest = _source_digest()
+        except OSError as e:
+            digest, last_err = None, e
+        for build_dir in _build_dirs() if digest else ():
+            out_dir = os.path.join(build_dir, digest)
+            so_path = os.path.join(out_dir, "libgreedy.so")
             try:
-                # A prebuilt .so without sources (stripped deploy) must
-                # load as-is; rebuild only when the source is present and
-                # newer.
-                stale = not os.path.exists(so_path) or (
-                    os.path.exists(src)
-                    and os.path.getmtime(so_path) < os.path.getmtime(src)
-                )
-                if stale:
-                    os.makedirs(build_dir, exist_ok=True)
-                    subprocess.run(
-                        ["make", "-B", "-C", _NATIVE_DIR,
-                         f"BUILD={build_dir}"],
-                        check=True,
-                        capture_output=True,
-                        text=True,
-                    )
+                if not os.path.exists(so_path):
+                    _build(out_dir)
                 lib = ctypes.CDLL(so_path)
                 break
             except (OSError, subprocess.CalledProcessError) as e:

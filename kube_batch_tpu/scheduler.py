@@ -192,7 +192,7 @@ class _WallClock:
 class Scheduler:
     # Per-cycle error backoff (capped exponential): a persistently
     # failing cycle must not busy-spin the loop, and a transient fault
-    # (an injected bind storm, a wedged backend probe) must not kill the
+    # (an injected bind storm, a hung device sync) must not kill the
     # process — the reference's wait.Until keeps the loop alive the same
     # way.
     CYCLE_ERROR_BACKOFF_BASE = 0.5

@@ -319,11 +319,12 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     add_sim_flags(parser)
     ns = parser.parse_args(argv)
+    from ..utils.backend import enable_compile_cache, force_cpu_devices
+
+    enable_compile_cache()
     if ns.host_devices:
         # Must precede ANY backend resolution (the harness's first
         # solve); re-shaping after a client exists is impossible.
-        from ..utils.backend import force_cpu_devices
-
         if not force_cpu_devices(ns.host_devices):
             print(
                 f"sim: --host-devices {ns.host_devices} requested but a "

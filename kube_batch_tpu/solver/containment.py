@@ -19,8 +19,7 @@ LIVENESS. Three cooperating pieces live here, consumed by
   the native floor (no device dispatch, no per-cycle failure latency).
   After a cooldown measured in CYCLES (wall time would break sim
   replay determinism) the breaker half-opens and runs a bounded canary
-  probe — a tiny last-good jitted solve, the in-cycle analog of the
-  ``ensure_live_backend`` startup probe — and re-closes on success.
+  probe — a tiny last-good jitted solve — and re-closes on success.
   The probe is synchronous but deadline-bounded, so re-promotion costs
   at most ``probe_timeout`` once per cooldown window.
 

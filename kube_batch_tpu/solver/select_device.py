@@ -656,7 +656,7 @@ def select_rows(
     # context must cover trace AND lowering (it is part of the jit
     # cache key, so every call goes through it). No 64-bit dtype
     # escapes — both outputs are i32.
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         cand_dev, count_dev = _topk_jit(kk, N)(eng.keys)
     cand = np.full((C, int(k)), N, np.int32)
     cand[:, :kk] = np.asarray(cand_dev)[:C]

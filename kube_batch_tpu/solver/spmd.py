@@ -53,16 +53,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.8 moved shard_map out of experimental
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=True):
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_rep,
-        )
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .kernels import (
     PackedInputs,
@@ -696,7 +687,7 @@ def _spmd_step(mesh: Mesh, staged, max_rounds, tail_bucket):
             # Replication of the outputs is by construction (the commit
             # runs on replicated operands on every shard); the static
             # checker cannot see through the while_loop carries.
-            check_rep=False,
+            check_vma=False,
         )
         return fn(inputs)
 
@@ -1337,7 +1328,7 @@ def _spmd_sparse_step(mesh: Mesh, max_rounds, tail_bucket, two_level):
             # Outputs are replicated by construction (every carry is
             # either gathered or psum-broadcast); the static checker
             # cannot see through the while_loop carries.
-            check_rep=False,
+            check_vma=False,
         )
         return fn(inputs)
 

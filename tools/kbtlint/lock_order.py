@@ -61,7 +61,7 @@ BLOCKING_CALL_NAMES = frozenset({
 # Modules whose in-project callees count as device dispatch.
 DEVICE_MODULE_SUFFIXES = (
     "solver/kernels.py", "solver/spmd.py", "solver/sharding.py",
-    "solver/pallas_kernels.py", "solver/device_cache.py",
+    "solver/device_cache.py",
 )
 
 _LOCK_CTORS = {"Lock": "lock", "RLock": "rlock"}

@@ -21,7 +21,7 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 TARGETS = ("kube_batch_tpu", "tests", "tools", "bench.py",
-           "__graft_entry__.py")
+           "chip_smoke.py", "__graft_entry__.py")
 
 
 def iter_py_files():
