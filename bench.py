@@ -560,7 +560,8 @@ def bench_obs(one_cycle, runs=7, cache=None):
             pass
     span_cost_us = (time.perf_counter() - t0) / probe_n * 1e6
     TRACER.reset()
-    TRACER.enabled = was_enabled
+    if not was_enabled:
+        TRACER.disable()  # and with it the GC hook
 
     # Telemetry enabled-path cost: a scratch Telemetry instance (the
     # global one must not absorb bench samples) fed a representative

@@ -446,7 +446,9 @@ class SchedulerCache(Cache, EventHandlersMixin):
 
         # Tracer handshake: side-effect spans adopt the submitting
         # span's id, so async binds/evicts render as worker-pool tracks
-        # nested under the cycle that queued them.
+        # nested under the cycle that queued them. They record their
+        # thread CPU too: the pool's workers share one GIL, so wall time
+        # alone cannot say where the work is.
         traced = TRACER.enabled
         parent = TRACER.capture() if traced else 0
         span_name = (
@@ -456,7 +458,8 @@ class SchedulerCache(Cache, EventHandlersMixin):
         def wrapped():
             try:
                 if traced:
-                    with TRACER.adopt(parent), _obs_span(span_name):
+                    with TRACER.adopt(parent), \
+                            _obs_span(span_name, cpu=True):
                         fn()
                 else:
                     fn()
@@ -527,22 +530,25 @@ class SchedulerCache(Cache, EventHandlersMixin):
 
     def _on_watch_event(self, kind: str, event_type: str, obj,
                         rv=None) -> None:
-        if rv is None:
-            self._dispatch_event(kind, event_type, obj)
-            return
-        # Admission and application are ATOMIC under the mutex: two
-        # concurrent deliveries for the same object could otherwise be
-        # admitted in rv order but applied inverted (B's DELETE rv=N+1
-        # lands between A's admit of rv=N and A's apply), resurrecting
-        # deleted state — exactly the regression the guard exists to
-        # prevent. The mutex is re-entrant; handlers take it anyway.
-        # Anomaly metrics flush AFTER the hold (no foreign locks under
-        # cache.mutex).
-        with self.mutex:
-            admitted = self._admit_event(kind, event_type, obj, rv)
-            if admitted:
+        # The ``ingest`` stage: on a bind worker this is the cache's own
+        # share of the cluster's synchronous watch fan-out.
+        with TRACER.stage("ingest"):
+            if rv is None:
                 self._dispatch_event(kind, event_type, obj)
-        self._flush_anomaly_metrics()
+                return
+            # Admission and application are ATOMIC under the mutex: two
+            # concurrent deliveries for the same object could otherwise
+            # be admitted in rv order but applied inverted (B's DELETE
+            # rv=N+1 lands between A's admit of rv=N and A's apply),
+            # resurrecting deleted state — exactly the regression the
+            # guard exists to prevent. The mutex is re-entrant; handlers
+            # take it anyway. Anomaly metrics flush AFTER the hold (no
+            # foreign locks under cache.mutex).
+            with TRACER.acquire(self.mutex), self.mutex:
+                admitted = self._admit_event(kind, event_type, obj, rv)
+                if admitted:
+                    self._dispatch_event(kind, event_type, obj)
+            self._flush_anomaly_metrics()
 
     def _dispatch_event(self, kind: str, event_type: str, obj) -> None:
         fn = self._dispatch.get((kind, event_type))
@@ -1080,12 +1086,14 @@ class SchedulerCache(Cache, EventHandlersMixin):
         # schedule on the freshest mirror available and let resync
         # reconcile. Deliberately NOT wait_for_side_effects: a slow
         # per-task volume bind must not stall the next cycle.
-        if not self.wait_for_bookkeeping(timeout=60.0):
+        with TRACER.stage("bookkeeping_wait"):
+            drained = self.wait_for_bookkeeping(timeout=60.0)
+        if not drained:
             logger.warning(
                 "bind bookkeeping still in flight after 60s; snapshotting "
                 "the current mirror state"
             )
-        with self.mutex:
+        with TRACER.acquire(self.mutex), self.mutex:
             snap = ClusterInfo()
             if (
                 self._snap_fp is not None
@@ -1610,25 +1618,31 @@ class SchedulerCache(Cache, EventHandlersMixin):
             # successor's recovery pass to classify against cluster
             # truth (a fenced leader cannot know what landed).
             return
+        from ..obs.latency import LEDGER
+
         try:
             self.volume_binder.bind_volumes(task_snapshot)
-            self.binder.bind(pod, hostname)
-            if mark_sink is not None:
-                mark_sink[task_snapshot.uid] = "applied"
-            else:
-                self._journal_mark(journal_seq, task_snapshot.uid, "applied")
-            # Placement-latency ledger: the applied stamp rides the
-            # journal-mark seam — the bind LANDED, so this timestamp is
-            # the truthful end of the pod's arrival→bind latency.
-            from ..obs.latency import LEDGER
-
-            LEDGER.note_applied(task_snapshot.uid)
+            # The cluster's synchronous watch fan-out runs inside this
+            # call, the cache's own ``ingest`` of the bind event included.
+            with TRACER.stage("bind_call"):
+                self.binder.bind(pod, hostname)
+            with TRACER.stage("ledgers"):
+                if mark_sink is not None:
+                    mark_sink[task_snapshot.uid] = "applied"
+                else:
+                    self._journal_mark(
+                        journal_seq, task_snapshot.uid, "applied")
+                # Placement-latency ledger: the applied stamp rides the
+                # journal-mark seam — the bind LANDED, so this timestamp
+                # is the truthful end of the pod's arrival→bind latency.
+                LEDGER.note_applied(task_snapshot.uid)
             if self.cluster is not None:
-                self.cluster.record_event(
-                    pod, "Normal", "Scheduled",
-                    f"Successfully assigned {pod.namespace}/{pod.name} "
-                    f"to {hostname}",
-                )
+                with TRACER.stage("event"):
+                    self.cluster.record_event(
+                        pod, "Normal", "Scheduled",
+                        f"Successfully assigned {pod.namespace}/{pod.name} "
+                        f"to {hostname}",
+                    )
         except Exception:
             try:
                 self.volume_binder.release_volumes(task_snapshot)
@@ -1636,15 +1650,15 @@ class SchedulerCache(Cache, EventHandlersMixin):
                 logger.exception(
                     "failed to release volumes of %s", task_snapshot.uid
                 )
-            if mark_sink is not None:
-                mark_sink[task_snapshot.uid] = "failed"
-            else:
-                self._journal_mark(journal_seq, task_snapshot.uid, "failed")
-            # Bind failure restarts the pod's latency clock (requeued
-            # stage): the next placement is measured from here.
-            from ..obs.latency import LEDGER
-
-            LEDGER.note_bind_failed(task_snapshot.uid)
+            with TRACER.stage("ledgers"):
+                if mark_sink is not None:
+                    mark_sink[task_snapshot.uid] = "failed"
+                else:
+                    self._journal_mark(
+                        journal_seq, task_snapshot.uid, "failed")
+                # Bind failure restarts the pod's latency clock (requeued
+                # stage): the next placement is measured from here.
+                LEDGER.note_bind_failed(task_snapshot.uid)
             self._resync_task(task_snapshot)
 
     def bind(self, task_info: TaskInfo, hostname: str) -> None:
@@ -1666,9 +1680,10 @@ class SchedulerCache(Cache, EventHandlersMixin):
                 from ..obs.latency import LEDGER
                 from ..obs.quality import QUALITY
 
-                LEDGER.note_dispatched((task_snapshot.uid,))
-                QUALITY.note_bound((task_snapshot.uid,))
-                seq = self._journal_append([task_snapshot])
+                with TRACER.stage("ledgers"):
+                    LEDGER.note_dispatched((task_snapshot.uid,))
+                    QUALITY.note_bound((task_snapshot.uid,))
+                    seq = self._journal_append([task_snapshot])
                 self._bind_side_effect(
                     pod, hostname, task_snapshot, journal_seq=seq
                 )
@@ -1730,7 +1745,8 @@ class SchedulerCache(Cache, EventHandlersMixin):
         # journal-before-any-side-effect ordering is preserved: every
         # bind of this batch is submitted from THIS job, below, and a
         # crash before this point leaves no cluster write to classify.
-        journal_seq = self._journal_append(task_infos)
+        with TRACER.stage("ledgers"):
+            journal_seq = self._journal_append(task_infos)
         binds = []
         slow_binds = []  # volume wait possible: isolate per task
         bound = []
@@ -1742,7 +1758,7 @@ class SchedulerCache(Cache, EventHandlersMixin):
         # forbids (it would stall snapshot/ingest and could trip the
         # watchdog on a slow API server).
         failed_marks: list = []
-        with self.mutex:
+        with TRACER.acquire(self.mutex), self.mutex:
             # hostname -> [(ti, stored, prior status/node for revert)]
             staged: Dict[str, list] = {}
             by_job: Dict[int, tuple] = {}  # id(job) -> (job, [stored])
@@ -1846,19 +1862,20 @@ class SchedulerCache(Cache, EventHandlersMixin):
                         revert(ti, stored, job, prior, hostname,
                                "rejected")
 
-        self._journal_mark_many(
-            journal_seq, {uid: "failed" for uid in failed_marks}
-        )
         # Placement-latency ledger (outside the mutex): staged binds
         # are DISPATCHED; validation failures / node rejections restart
         # their pods' clocks exactly like an async bind failure.
         from ..obs.latency import LEDGER
         from ..obs.quality import QUALITY
 
-        LEDGER.note_dispatched([t.uid for t in bound])
-        QUALITY.note_bound([t.uid for t in bound])
-        for uid in failed_marks:
-            LEDGER.note_bind_failed(uid, reason="bind-rejected")
+        with TRACER.stage("ledgers"):
+            self._journal_mark_many(
+                journal_seq, {uid: "failed" for uid in failed_marks}
+            )
+            LEDGER.note_dispatched([t.uid for t in bound])
+            QUALITY.note_bound([t.uid for t in bound])
+            for uid in failed_marks:
+                LEDGER.note_bind_failed(uid, reason="bind-rejected")
 
         # Pre-warm the COW snapshot pool for everything this batch
         # dirtied: re-clone the touched jobs/nodes HERE, on the
@@ -1878,10 +1895,10 @@ class SchedulerCache(Cache, EventHandlersMixin):
         # to a swap mid-loop except on barrier timeout — where dropped
         # entries only cost a re-clone.
         for job, _ in by_job.values():
-            with self.mutex:
+            with TRACER.acquire(self.mutex), self.mutex:
                 self._snap_pool[0][job.uid] = _pool_entry(job)
         for hostname in staged:
-            with self.mutex:
+            with TRACER.acquire(self.mutex), self.mutex:
                 node = self.nodes.get(hostname)
                 if node is not None:
                     self._snap_pool[1][hostname] = _pool_entry(node)
@@ -1898,7 +1915,8 @@ class SchedulerCache(Cache, EventHandlersMixin):
                         pod, hostname, task_snapshot,
                         journal_seq=journal_seq, mark_sink=marks,
                     )
-                self._journal_mark_many(journal_seq, marks)
+                with TRACER.stage("ledgers"):
+                    self._journal_mark_many(journal_seq, marks)
 
             for start in range(0, len(binds), self._BIND_CHUNK):
                 chunk = binds[start:start + self._BIND_CHUNK]

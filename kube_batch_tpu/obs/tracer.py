@@ -19,6 +19,19 @@ Design constraints (ISSUE 5 tentpole):
   overlapped solve/apply window as concurrent tracks; ``args`` carry
   the owning cycle and parent span id for programmatic assertions.
 
+- **Where the time went, not only how long.** :meth:`Tracer.stage`
+  sums wall time and a count per named stage into the innermost open
+  span of the calling thread, and thread CPU time (``time.thread_time``)
+  over every ``CPU_EVERY``-th entry, less the collections and clock
+  reads inside the entry; a span opened with ``cpu=True`` also
+  records its own thread-CPU delta (``cpu_s``). Wall time alone blames
+  whichever stage happened to wait for the GIL or a lock; CPU time does
+  not depend on who waits.
+- **The runtime's own pauses.** While enabled, a ``gc.callbacks`` hook
+  records a ``gc`` span per collection on the collecting thread, and a
+  ``jax.monitoring`` listener records ``compile`` and
+  ``compile_cache_load`` spans. Every span is on ``time.perf_counter``.
+
 ``KBT_TRACE_DIR`` enables tracing process-wide (the scheduler loop and
 the guarded error path export there); bench ``--trace`` and sim
 ``--trace-out`` enable it explicitly for one run. ``KBT_TRACE_JAX=1``
@@ -28,6 +41,7 @@ additionally wraps solver-stage spans in
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
@@ -35,6 +49,10 @@ import threading
 import time
 from collections import deque
 from typing import Optional
+
+# Clock reads on the recording paths, bound once.
+_perf = time.perf_counter
+_cpu = time.thread_time
 
 TRACE_DIR_ENV = "KBT_TRACE_DIR"
 TRACE_JAX_ENV = "KBT_TRACE_JAX"
@@ -64,6 +82,37 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+# jax.monitoring duration events recorded as spans, by span name.
+_JAX_SPANS = {
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile_cache_load",
+}
+
+# A stage reads the thread-CPU clock on every CPU_EVERY-th entry only
+# (the first included): on the v5e host a ``thread_time`` read costs
+# 5.6–6.6 µs against 0.13–0.16 µs for ``perf_counter``, which made per-bind
+# stages stretch the bind drain. ``<name>_cpu_n`` counts the read entries;
+# ``<name>_cpu_s * <name>_n / <name>_cpu_n`` estimates the stage's CPU.
+# A read entry leaves out what is not the stage's own work and would be
+# scaled by CPU_EVERY with it: the collections its thread ran inside it
+# (a full one takes over a second at 50k pods; ``gc`` spans report it),
+# and the clock reads themselves, its own and those of read entries of
+# other stages nested in it. A read's cost is its wall time, taken in
+# place (contention on the v5e host stretches it).
+CPU_EVERY = 8
+
+# stage name -> its (wall, count, thread-CPU, CPU-read count) arg keys.
+_STAGE_KEYS: dict = {}
+
+
+def _stage_keys(name: str) -> tuple:
+    keys = _STAGE_KEYS.get(name)
+    if keys is None:
+        keys = _STAGE_KEYS[name] = (
+            f"{name}_s", f"{name}_n", f"{name}_cpu_s", f"{name}_cpu_n")
+    return keys
+
+
 # Sentinel distinguishing "no adopted cycle override" from an adopted
 # cycle that is legitimately None.
 _UNSET = object()
@@ -71,14 +120,18 @@ _UNSET = object()
 
 class _Span:
     __slots__ = (
-        "tracer", "name", "args", "sid", "parent", "cycle", "t0",
-        "_jax_ctx",
+        "tracer", "name", "args", "sid", "parent", "cycle", "t0", "c0",
+        "stages", "_jax_ctx",
     )
 
-    def __init__(self, tracer: "Tracer", name: str, args, jax_annotate):
+    def __init__(self, tracer: "Tracer", name: str, args, jax_annotate,
+                 cpu=False):
         self.tracer = tracer
         self.name = name
         self.args = args
+        # Thread-CPU clock at entry when the span records ``cpu_s``.
+        self.c0 = 0.0 if cpu else None
+        self.stages = None  # name -> _Stage, built on first entry
         self._jax_ctx = None
         if jax_annotate and tracer.jax_annotations:
             try:
@@ -96,7 +149,7 @@ class _Span:
             stack = tls.stack = []
         self.sid = next(t._ids)
         self.parent = (
-            stack[-1] if stack else getattr(tls, "adopted", 0)
+            stack[-1].sid if stack else getattr(tls, "adopted", 0)
         )
         # Owning cycle, resolved at ENTRY: an adopted worker span (and
         # anything nested under it) belongs to the cycle that queued
@@ -104,24 +157,124 @@ class _Span:
         # global cycle counter by the time the worker drains.
         override = getattr(tls, "adopted_cycle", _UNSET)
         self.cycle = t.cycle if override is _UNSET else override
-        stack.append(self.sid)
+        stack.append(self)
         if self._jax_ctx is not None:
             self._jax_ctx.__enter__()
-        self.t0 = time.perf_counter()
+        # Wall clock outside the CPU clock, at both ends: the CPU
+        # interval lies inside the wall one.
+        self.t0 = _perf()
+        if self.c0 is not None:
+            self.c0 = _cpu()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
+        c0 = self.c0
+        cpu_s = None if c0 is None else _cpu() - c0
+        t1 = _perf()
+        if cpu_s is not None or self.stages:
+            if self.args is None:
+                self.args = {}
+            if cpu_s is not None:
+                self.args["cpu_s"] = cpu_s
+            if self.stages:
+                for stage in self.stages.values():
+                    stage.write(self.args)
         t = self.tracer
         if self._jax_ctx is not None:
             self._jax_ctx.__exit__(*exc)
         stack = t._tls.stack
-        if stack and stack[-1] == self.sid:
+        if stack and stack[-1] is self:
             stack.pop()
         t._record(
             self.name, self.t0, t1, self.sid, self.parent, self.cycle,
             self.args,
         )
+        return False
+
+
+class _Stage:
+    """A named stage of one span: sums each entry's wall time and a count,
+    and on every ``CPU_EVERY``-th entry its thread CPU time less the
+    collections and clock reads inside it; the span writes them into its
+    args at exit (``<name>_s``, ``<name>_n``, ``<name>_cpu_s``,
+    ``<name>_cpu_n``). One object per span and name, entered again each
+    time (a name does not nest in itself)."""
+
+    __slots__ = ("tls", "keys", "seen", "wall", "cpu", "cpu_n", "t0", "c0",
+                 "x0")
+
+    def __init__(self, tls, name: str):
+        self.tls = tls
+        self.keys = _stage_keys(name)
+        self.seen = 0
+        self.wall = 0.0
+        self.cpu = 0.0
+        self.cpu_n = 0
+        self.c0 = None
+
+    def __enter__(self):
+        self.t0 = _perf()
+        # CPU read inside the wall reads, on the sampled entries.
+        if self.seen % CPU_EVERY:
+            self.c0 = None
+        else:
+            self.x0 = getattr(self.tls, "cpu_excluded", 0.0)
+            self.c0 = _cpu()
+        self.seen += 1
+        return self
+
+    def __exit__(self, *exc):
+        c0 = self.c0
+        if c0 is None:
+            self.wall += _perf() - self.t0
+            return False
+        r0 = _perf()
+        c1 = _cpu()
+        t1 = _perf()
+        read = t1 - r0
+        tls = self.tls
+        excluded = getattr(tls, "cpu_excluded", 0.0)
+        # The span between the two reads holds about one read's cost.
+        self.cpu += c1 - c0 - (excluded - self.x0) - read
+        self.cpu_n += 1
+        # Both reads lie inside any read entry this one is nested in.
+        tls.cpu_excluded = excluded + 2 * read
+        self.wall += t1 - self.t0
+        return False
+
+    def write(self, args: dict) -> None:
+        k_s, k_n, k_cpu, k_cpu_n = self.keys
+        args[k_s] = self.wall
+        args[k_n] = self.seen
+        if self.cpu_n:
+            args[k_cpu] = self.cpu
+            args[k_cpu_n] = self.cpu_n
+
+
+class _TimedAcquire:
+    """Takes ``lock`` with the wait added to the ``mutex_wait`` stage as
+    wall time and a count (a blocked wait burns no CPU, so none is read),
+    and releases it at exit. An uncontended lock is taken without a wait
+    to time."""
+
+    __slots__ = ("stage", "lock")
+
+    def __init__(self, stage: _Stage, lock):
+        self.stage = stage
+        self.lock = lock
+
+    def __enter__(self):
+        lock = self.lock
+        stage = self.stage
+        if not lock.acquire(False):
+            t0 = _perf()
+            lock.acquire()
+            stage.wall += _perf() - t0
+        stage.seen += 1
+        return self
+
+    def __exit__(self, *exc):
+        self.lock.release()
         return False
 
 
@@ -139,12 +292,7 @@ class _Adopt:
         tls = self.tracer._tls
         self._prev = getattr(tls, "adopted", 0)
         self._prev_cycle = getattr(tls, "adopted_cycle", _UNSET)
-        token = self.token
-        if isinstance(token, tuple):
-            sid, cycle = token
-        else:
-            # Back-compat: a bare span id adopts the live cycle.
-            sid, cycle = token, _UNSET
+        sid, cycle = self.token
         tls.adopted = sid or 0
         tls.adopted_cycle = cycle
         return self
@@ -170,17 +318,32 @@ class Tracer:
         self._ids = itertools.count(1)  # count().__next__ is atomic
         self._epoch = time.perf_counter()
         self._pid = os.getpid()
+        self._gc_t0 = None
+        self._gc_c0 = 0.0
+        self._jax_listening = False
 
     # -- lifecycle ----------------------------------------------------------
 
-    def enable(self, capacity: Optional[int] = None) -> None:
-        if capacity is not None and capacity != self.capacity:
-            self.capacity = capacity
-            self._events = deque(self._events, maxlen=capacity)
+    def enable(self) -> None:
+        """Start recording, with the GC hook installed and (once per
+        tracer) the JAX compile listener registered."""
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+        if not self._jax_listening:
+            self._jax_listening = True
+            try:
+                import jax.monitoring
+            except ImportError:  # pragma: no cover - jax absent
+                pass
+            else:
+                jax.monitoring.register_event_duration_secs_listener(
+                    self._on_jax_duration)
         self.enabled = True
 
     def disable(self) -> None:
         self.enabled = False
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
 
     def reset(self) -> None:
         """Drop buffered events and stats (keeps enabled state).
@@ -193,12 +356,52 @@ class Tracer:
 
     # -- spans --------------------------------------------------------------
 
-    def span(self, name: str, jax_annotate: bool = False, **args):
+    def span(self, name: str, jax_annotate: bool = False, cpu: bool = False,
+             **args) -> "_Span | _NullSpan":
         if not self.enabled:
             return _NULL
-        return _Span(self, name, args or None, jax_annotate)
+        return _Span(self, name, args or None, jax_annotate, cpu)
 
-    def begin_cycle(self, cycle) -> None:
+    def _stage(self, name: str) -> Optional[_Stage]:
+        """Stage ``name`` of the current thread's innermost open span,
+        made on first use; None with no open span."""
+        stack = getattr(self._tls, "stack", None)
+        if not stack:
+            return None
+        span = stack[-1]
+        stages = span.stages
+        if stages is None:
+            stages = span.stages = {}
+        stage = stages.get(name)
+        if stage is None:
+            stage = stages[name] = _Stage(self._tls, name)
+        return stage
+
+    def stage(self, name: str) -> "_Stage | _NullSpan":
+        """Add this block's wall time and a count to the innermost open
+        span of the current thread, as that span's ``<name>_s`` and
+        ``<name>_n`` args, and on every ``CPU_EVERY``-th entry its thread
+        CPU time outside collections and clock reads (``<name>_cpu_s``,
+        ``<name>_cpu_n``). Stages of different names nest (each is its own
+        sum); a name must not nest inside itself. With no open span on the
+        thread, or disabled, it records nothing."""
+        if not self.enabled:
+            return _NULL
+        stage = self._stage(name)
+        return _NULL if stage is None else stage
+
+    def acquire(self, lock: object) -> "_TimedAcquire | _NullSpan":
+        """Take ``lock`` with the wait summed as stage ``mutex_wait`` (wall
+        time and count only) and hold it to the block's end. For a
+        re-entrant lock the caller writes ``with TRACER.acquire(lock),
+        lock:`` so the locked region stays visible as a ``with lock``
+        block; the inner entry is a re-entry."""
+        if not self.enabled:
+            return _NULL
+        stage = self._stage("mutex_wait")
+        return _NULL if stage is None else _TimedAcquire(stage, lock)
+
+    def begin_cycle(self, cycle: object) -> None:
         """Stamp the cycle id every subsequent span's args carry (worker
         threads included, via capture/adopt)."""
         self.cycle = cycle
@@ -238,13 +441,13 @@ class Tracer:
             t1 = time.perf_counter()
         tls = self._tls
         stack = getattr(tls, "stack", None)
-        parent = stack[-1] if stack else getattr(tls, "adopted", 0)
+        parent = stack[-1].sid if stack else getattr(tls, "adopted", 0)
         override = getattr(tls, "adopted_cycle", _UNSET)
         cycle = self.cycle if override is _UNSET else override
         self._record(name, t0, t1, next(self._ids), parent, cycle,
                      args or None)
 
-    def capture(self):
+    def capture(self) -> tuple:
         """Opaque token — (current span id, owning cycle) of THIS
         thread — for a worker to ``adopt`` so its spans nest under the
         submitting span AND keep the submitting cycle's stamp even when
@@ -256,11 +459,44 @@ class Tracer:
         cycle = self.cycle if override is _UNSET else override
         stack = getattr(tls, "stack", None)
         if stack:
-            return (stack[-1], cycle)
+            return (stack[-1].sid, cycle)
         return (getattr(tls, "adopted", 0), cycle)
 
-    def adopt(self, token) -> _Adopt:
+    def adopt(self, token: tuple) -> _Adopt:
         return _Adopt(self, token)
+
+    # -- runtime hooks ------------------------------------------------------
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook: one ``gc`` span per collection, and the
+        collection's thread CPU added to what stages on the thread leave
+        out. CPython runs one collection at a time, on the
+        thread that triggered it."""
+        if phase == "start":
+            self._gc_t0 = _perf()
+            self._gc_c0 = _cpu()
+            return
+        t0, self._gc_t0 = self._gc_t0, None
+        if t0 is None:
+            return
+        tls = self._tls
+        tls.cpu_excluded = getattr(tls, "cpu_excluded", 0.0) + (
+            _cpu() - self._gc_c0)
+        self.complete("gc", t0, _perf(), generation=info["generation"],
+                      collected=info["collected"])
+
+    def _on_jax_duration(self, event: str, duration: float, **kwargs) -> None:
+        """``jax.monitoring`` listener: a compile or a persistent-cache
+        load as a span ending now, on this tracer's clock."""
+        name = _JAX_SPANS.get(event)
+        if name is None or not self.enabled:
+            return
+        t1 = time.perf_counter()
+        if name == "compile":
+            self.complete(name, t1 - duration, t1,
+                          fun=kwargs.get("fun_name"))
+        else:
+            self.complete(name, t1 - duration, t1)
 
     # -- export -------------------------------------------------------------
 
@@ -316,12 +552,13 @@ class Tracer:
 TRACER = Tracer()
 
 
-def span(name: str, jax_annotate: bool = False, **args):
+def span(name: str, jax_annotate: bool = False, cpu: bool = False,
+         **args) -> "_Span | _NullSpan":
     """Module-level convenience: ``with obs.span("solve"): ...``."""
     t = TRACER
     if not t.enabled:
         return _NULL
-    return _Span(t, name, args or None, jax_annotate)
+    return _Span(t, name, args or None, jax_annotate, cpu)
 
 
 def export_trace(path: Optional[str] = None, tag: str = "trace") -> Optional[str]:

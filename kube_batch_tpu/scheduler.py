@@ -496,7 +496,8 @@ class Scheduler:
         while not stop.is_set():
             start = clock.now()
             if not self.run_once_guarded():
-                clock.wait(stop, self.cycle_error_backoff())
+                with span("loop_wait", phase="backoff"):
+                    clock.wait(stop, self.cycle_error_backoff())
                 continue
             elapsed = clock.now() - start
             remaining = max(0.0, self.schedule_period - elapsed)
@@ -509,14 +510,15 @@ class Scheduler:
                 # the stop event stays responsive mid-drain.
                 deadline = time.perf_counter() + remaining
                 try:
-                    while not stop.is_set():
-                        left = deadline - time.perf_counter()
-                        if left <= 0:
-                            break
-                        if self.cache.wait_for_side_effects(
-                            timeout=min(0.2, left)
-                        ):
-                            break
+                    with span("loop_wait", phase="drain"):
+                        while not stop.is_set():
+                            left = deadline - time.perf_counter()
+                            if left <= 0:
+                                break
+                            if self.cache.wait_for_side_effects(
+                                timeout=min(0.2, left)
+                            ):
+                                break
                 except Exception:
                     logger.exception("think-time side-effect drain failed")
                 if self.micro_enabled and self._micro_wait(stop, deadline):
@@ -526,7 +528,8 @@ class Scheduler:
                     self.early_fairness_passes += 1
                     continue
                 remaining = max(0.0, deadline - time.perf_counter())
-            clock.wait(stop, remaining)
+            with span("loop_wait", phase="sleep"):
+                clock.wait(stop, remaining)
         # Loop exit with tracing armed (KBT_TRACE_DIR): persist the
         # buffered spans so an operator-stopped run leaves a trace.
         export_trace(tag="trace")
@@ -640,11 +643,14 @@ class Scheduler:
             left = deadline - time.perf_counter()
             if left <= 0:
                 return False
-            if not self._micro_arrival.wait(timeout=min(left, 0.25)):
+            with span("micro_park"):
+                arrived = self._micro_arrival.wait(timeout=min(left, 0.25))
+            if not arrived:
                 continue
             window = self._micro_tuned_window()
             if window > 0:
-                stop.wait(window)
+                with span("micro_coalesce", window_s=window):
+                    stop.wait(window)
             self._micro_arrival.clear()
             used += 1
             try:
@@ -729,22 +735,24 @@ class Scheduler:
         # cycle's still-open flight record (micro cycles count toward
         # the KBT_QUALITY_EVERY cadence exactly like the telemetry
         # probes — under micro-primary steady state the card must not
-        # go stale). Guarded: a probe failure never fails a cycle.
-        try:
-            from .obs.quality import QUALITY
-
-            QUALITY.annotate_cycle(self.cache)
-        except Exception:
-            logger.exception("quality cycle feed failed")
-        rec = RECORDER.end_cycle(ok=ok, e2e_ms=round(e2e * 1e3, 3))
-        self.micro_cycles_run += 1
-        if self._telemetry:
+        # go stale). Guarded: a probe failure never fails a cycle. The
+        # feeds' cadenced probes scan the cache, hence their own span.
+        with span("observe_cycle"):
             try:
-                from .obs.telemetry import TELEMETRY
+                from .obs.quality import QUALITY
 
-                TELEMETRY.observe_scheduler_cycle(rec, cache=self.cache)
+                QUALITY.annotate_cycle(self.cache)
             except Exception:
-                logger.exception("telemetry cycle feed failed")
+                logger.exception("quality cycle feed failed")
+            rec = RECORDER.end_cycle(ok=ok, e2e_ms=round(e2e * 1e3, 3))
+            self.micro_cycles_run += 1
+            if self._telemetry:
+                try:
+                    from .obs.telemetry import TELEMETRY
+
+                    TELEMETRY.observe_scheduler_cycle(rec, cache=self.cache)
+                except Exception:
+                    logger.exception("telemetry cycle feed failed")
         return ok
 
     def _on_watchdog_trip(self, reason: str) -> None:
@@ -856,20 +864,21 @@ class Scheduler:
         metrics.update_e2e_duration(e2e)
         RECORDER.phase("done")
         # Quality scorecard BEFORE end_cycle (see run_micro).
-        try:
-            from .obs.quality import QUALITY
-
-            QUALITY.annotate_cycle(self.cache)
-        except Exception:
-            logger.exception("quality cycle feed failed")
-        rec = RECORDER.end_cycle(e2e_ms=round(e2e * 1e3, 3))
-        # Long-horizon telemetry: fold this cycle's record + resource
-        # watermarks into the time-series (obs/telemetry.py). Guarded —
-        # a probe failure must never fail a cycle.
-        if self._telemetry:
+        with span("observe_cycle"):
             try:
-                from .obs.telemetry import TELEMETRY
+                from .obs.quality import QUALITY
 
-                TELEMETRY.observe_scheduler_cycle(rec, cache=self.cache)
+                QUALITY.annotate_cycle(self.cache)
             except Exception:
-                logger.exception("telemetry cycle feed failed")
+                logger.exception("quality cycle feed failed")
+            rec = RECORDER.end_cycle(e2e_ms=round(e2e * 1e3, 3))
+            # Long-horizon telemetry: fold this cycle's record + resource
+            # watermarks into the time-series (obs/telemetry.py). Guarded
+            # — a probe failure must never fail a cycle.
+            if self._telemetry:
+                try:
+                    from .obs.telemetry import TELEMETRY
+
+                    TELEMETRY.observe_scheduler_cycle(rec, cache=self.cache)
+                except Exception:
+                    logger.exception("telemetry cycle feed failed")

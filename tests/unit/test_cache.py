@@ -492,3 +492,71 @@ class TestSnapshotPool:
         )
         s2 = c.snapshot()
         assert s2.jobs["ns/pgp"].priority == 100
+
+
+class TestBindPathStages:
+    """The bind path's stage counters, on a cluster whose bind delivers
+    its watch event synchronously (the cache's ingest runs inside the
+    bind call)."""
+
+    def test_bind_batch_chunk_spans_carry_nested_stages(self):
+        import threading
+
+        from kube_batch_tpu.cluster import InProcessCluster
+        from kube_batch_tpu.obs.tracer import TRACER
+
+        cluster = InProcessCluster(simulate_kubelet=True)
+        cluster.create_queue(build_queue("default", 1))
+        cluster.create_node(
+            build_node("n1", build_resource_list(cpu="8", memory="16Gi")))
+        cluster.create_pod_group(
+            build_pod_group("pg1", namespace="ns", min_member=1))
+        for i in range(6):
+            cluster.create_pod(build_pod(
+                "ns", f"p{i}", "", PodPhase.PENDING,
+                build_resource_list(cpu="500m", memory="256Mi"),
+                group_name="pg1"))
+        c = SchedulerCache(cluster=cluster)
+        c._BIND_CHUNK = 4
+        stop = threading.Event()
+        c.run(stop)
+        try:
+            assert c.wait_for_cache_sync(stop)
+            infos = []
+            for task in c.jobs["ns/pg1"].tasks.values():
+                info = task.clone()
+                info.node_name = "n1"
+                info.volume_ready = True
+                infos.append(info)
+            TRACER.reset()
+            TRACER.enable()
+            try:
+                with TRACER.span("cycle"):
+                    c.bind_batch(infos)
+                assert c.wait_for_side_effects(timeout=10)
+            finally:
+                TRACER.disable()
+            events = TRACER.events()
+            TRACER.reset()
+        finally:
+            stop.set()
+        chunks = [e for e in events if e["name"] == "cache_side_effect"]
+        assert sorted(e["args"]["ingest_n"] for e in chunks) == [2, 4]
+        for e in chunks:
+            a = e["args"]
+            # the cache's ingest runs inside the cluster's bind call
+            assert a["bind_call_n"] == a["event_n"] == a["ingest_n"]
+            assert a["ingest_s"] <= a["bind_call_s"]
+            assert a["bind_call_s"] + a["ledgers_s"] + a["event_s"] \
+                <= e["dur"] / 1e6
+            assert a["ingest_cpu_s"] <= a["bind_call_cpu_s"]
+            assert a["bind_call_cpu_s"] + a["ledgers_cpu_s"] \
+                + a["event_cpu_s"] <= a["cpu_s"]
+            assert a["mutex_wait_n"] == a["ingest_n"]
+            assert "mutex_wait_cpu_s" not in a  # a lock wait is wall only
+            # per bind, plus the chunk's journal-mark flush
+            assert a["ledgers_n"] == a["ingest_n"] + 1
+        (book,) = [e for e in events if e["name"] == "cache_bookkeeping"]
+        assert book["args"]["cpu_s"] > 0 and book["args"]["ledgers_n"] == 2
+        # staging hold, then the prewarm holds (one job, one node)
+        assert book["args"]["mutex_wait_n"] == 3

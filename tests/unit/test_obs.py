@@ -3,6 +3,7 @@ nesting, export), flight-recorder ring (wraparound, error capture,
 SIGUSR1 dump roundtrip), and the /debug HTTP surface.
 """
 
+import gc
 import json
 import os
 import signal
@@ -14,7 +15,8 @@ from urllib.error import HTTPError
 import pytest
 
 from kube_batch_tpu.obs.flightrecorder import FlightRecorder, install_sigusr1
-from kube_batch_tpu.obs.tracer import Tracer
+from kube_batch_tpu.obs import tracer as tracer_mod
+from kube_batch_tpu.obs.tracer import _NULL, Tracer
 
 
 # ------------------------------------------------------------------ tracer
@@ -154,14 +156,26 @@ def test_tracer_thread_safety_under_contention():
         th.start()
     for th in threads:
         th.join()
-    events = t.events()
+    # Collections the hammering triggers add their own ``gc`` spans.
+    events = [e for e in t.events() if e["name"] == "s"]
     assert len(events) == n_threads * per_thread
-    assert t.spans_recorded == n_threads * per_thread
+    assert t.spans_recorded == len(t.events())
     sids = [e["args"]["sid"] for e in events]
     assert len(set(sids)) == len(sids)  # unique span ids
 
 
-def test_event_ring_caps_memory():
+@pytest.fixture
+def no_auto_gc():
+    """No automatic collection mid-test: an enabled tracer records each
+    as a ``gc`` span, which would break exact span counts."""
+    was = gc.isenabled()
+    gc.disable()
+    yield
+    if was:
+        gc.enable()
+
+
+def test_event_ring_caps_memory(no_auto_gc):
     t = Tracer(capacity=10)
     t.enable()
     for i in range(25):
@@ -174,7 +188,7 @@ def test_event_ring_caps_memory():
     assert t.events()[-1]["name"] == "s24"
 
 
-def test_export_chrome_trace(tmp_path):
+def test_export_chrome_trace(tmp_path, no_auto_gc):
     t = Tracer()
     t.enable()
     with t.span("a"):
@@ -188,6 +202,317 @@ def test_export_chrome_trace(tmp_path):
     metas = [e for e in events if e.get("ph") == "M"]
     assert {e["name"] for e in xs} == {"a", "b"}
     assert metas and metas[0]["name"] == "thread_name"
+
+
+def _args(t, name):
+    return [e["args"] for e in t.events() if e["name"] == name]
+
+
+def test_stage_sums_nest_and_stay_per_thread():
+    t = Tracer()
+    t.enable()
+    with t.span("outer"):
+        for _ in range(2):
+            with t.stage("call"):
+                with t.stage("ingest"):
+                    with t.stage("wait"):
+                        time.sleep(0.002)
+                    time.sleep(0.001)
+
+        def other():
+            with t.span("other"):
+                with t.stage("ingest"):
+                    pass
+
+        th = threading.Thread(target=other)
+        th.start()
+        th.join(timeout=10)
+        assert not th.is_alive()
+    (outer,), (other,) = _args(t, "outer"), _args(t, "other")
+    assert outer["call_n"] == outer["ingest_n"] == 2
+    assert outer["wait_n"] == 2
+    assert outer["call_s"] >= outer["ingest_s"] >= outer["wait_s"]
+    assert outer["wait_s"] >= 0.004
+    for stage in ("call", "ingest", "wait"):
+        # The first entry of a stage reads the CPU clock, the second not.
+        assert outer[f"{stage}_cpu_n"] == 1
+        # less the clock reads' own cost, so a near-idle stage can dip
+        assert -1e-4 <= outer[f"{stage}_cpu_s"] <= outer[f"{stage}_s"]
+    # The other thread's stage went to its own span only.
+    assert other["ingest_n"] == 1 and "call_n" not in other
+
+
+def test_stage_reads_the_cpu_clock_on_every_nth_entry():
+    t = Tracer()
+    t.enable()
+    n = 3 * tracer_mod.CPU_EVERY + 1
+    with t.span("s"):
+        for _ in range(n):
+            with t.stage("x"):
+                pass
+    (args,) = _args(t, "s")
+    assert args["x_n"] == n and args["x_cpu_n"] == 4
+    assert "mutex_wait_cpu_n" not in args
+
+
+def test_stage_goes_to_the_innermost_span():
+    t = Tracer()
+    t.enable()
+    with t.span("outer"):
+        with t.span("inner"):
+            with t.stage("ledgers"):
+                pass
+    assert "ledgers_n" not in _args(t, "outer")[0]
+    assert _args(t, "inner")[0]["ledgers_n"] == 1
+
+
+def test_disabled_tracer_records_nothing_and_installs_no_gc_hook():
+    t = Tracer()
+    assert t.stage("ingest") is _NULL
+    assert t.acquire(threading.Lock()) is _NULL
+    with t.span("a", cpu=True), t.stage("ingest"):
+        pass
+    gc.collect()
+    assert t.events() == [] and t.spans_recorded == 0
+    assert t._on_gc not in gc.callbacks
+    t.enable()
+    assert t._on_gc in gc.callbacks
+    t.disable()
+    assert t._on_gc not in gc.callbacks
+
+
+def test_disabled_sites_test_one_flag_and_read_no_clock(monkeypatch):
+    """Tracing off, each instrumented site costs one ``enabled`` test: no
+    clock read, no allocation, the shared no-op returned."""
+    reads = []
+
+    class Counting(Tracer):
+        @property
+        def enabled(self):
+            reads.append(1)
+            return False
+
+        @enabled.setter
+        def enabled(self, value):
+            pass
+
+    t = Counting()
+
+    def no_clock():
+        raise AssertionError("clock read on the disabled path")
+
+    monkeypatch.setattr(tracer_mod, "_perf", no_clock)
+    monkeypatch.setattr(tracer_mod, "_cpu", no_clock)
+    monkeypatch.setattr(time, "perf_counter", no_clock)
+    for site in (lambda: t.span("s", cpu=True), lambda: t.stage("ingest"),
+                 lambda: t.acquire(threading.Lock())):
+        reads.clear()
+        with site() as cm:
+            assert cm is _NULL
+        assert len(reads) == 1
+
+
+def test_stage_outside_any_span_records_nothing():
+    t = Tracer()
+    t.enable()
+    assert t.stage("ingest") is _NULL
+    lock = threading.RLock()
+    with t.acquire(lock), lock:
+        pass
+    assert [e for e in t.events() if e["name"] != "gc"] == []
+
+
+def test_acquire_times_the_wait_and_holds_the_lock():
+    t = Tracer()
+    t.enable()
+    lock = threading.RLock()
+    held = threading.Event()
+
+    def other_holder():
+        with lock:
+            held.set()
+            time.sleep(0.02)
+
+    th = threading.Thread(target=other_holder)
+    th.start()
+    held.wait(timeout=5)
+    with t.span("waiter"):
+        with t.acquire(lock), lock:
+            # Held here, and by this thread: another cannot take it.
+            assert not _try_from_other_thread(lock)
+    th.join(timeout=10)
+    assert not th.is_alive()
+    assert _try_from_other_thread(lock)
+    (args,) = _args(t, "waiter")
+    assert args["mutex_wait_n"] == 1 and args["mutex_wait_s"] >= 0.015
+    assert "mutex_wait_cpu_s" not in args  # a blocked wait burns no CPU
+
+
+def _try_from_other_thread(lock):
+    got = []
+
+    def attempt():
+        if lock.acquire(blocking=False):
+            lock.release()
+            got.append(True)
+
+    th = threading.Thread(target=attempt)
+    th.start()
+    th.join(timeout=10)
+    assert not th.is_alive()
+    return bool(got)
+
+
+def test_cpu_span_records_its_thread_cpu():
+    t = Tracer()
+    t.enable()
+    with t.span("busy", cpu=True, k=1):
+        deadline = time.perf_counter() + 0.02
+        while time.perf_counter() < deadline:
+            pass
+    with t.span("plain"):
+        pass
+    (busy,), (plain,) = _args(t, "busy"), _args(t, "plain")
+    assert busy["k"] == 1
+    assert 0.005 <= busy["cpu_s"] <= 0.2
+    assert "cpu_s" not in plain
+
+
+def test_gc_collect_yields_a_gc_span():
+    t = Tracer()
+    t.enable()
+    with t.span("around"):
+        gc.collect()
+    t.disable()
+    spans = [e for e in t.events() if e["name"] == "gc"]
+    full = [e for e in spans if e["args"]["generation"] == 2]
+    assert full and full[-1]["args"]["collected"] >= 0
+    around = _args(t, "around")[0]
+    assert full[-1]["args"]["parent"] == around["sid"]
+
+
+def test_stage_cpu_leaves_out_collections():
+    """A collection inside a stage's sampled entry is the ``gc`` span's,
+    not the stage's: scaled by CPU_EVERY it would swamp the stage."""
+    t = Tracer()
+    t.enable()
+    keep = [[i] for i in range(300_000)]  # a full collection walks these
+    with t.span("s"):
+        with t.stage("x"):
+            gc.collect()
+    t.disable()
+    del keep
+    (args,) = _args(t, "s")
+    gc_s = sum(e["dur"] for e in t.events() if e["name"] == "gc"
+               and e["args"]["parent"] == args["sid"]) / 1e6
+    assert gc_s > 0.005
+    assert args["x_cpu_n"] == 1 and args["x_s"] >= gc_s
+    assert abs(args["x_cpu_s"]) < 0.5 * gc_s
+
+
+def test_stage_cpu_leaves_out_its_clock_reads(monkeypatch, no_auto_gc):
+    """On fake clocks where a CPU read costs 5 µs: a read entry holds about
+    one read of its own and both reads of a nested read entry, and scaled
+    by CPU_EVERY they would count as the stage's work."""
+    now = [0.0]  # one busy thread: CPU and wall advance together
+
+    def cpu():
+        now[0] += 2.5e-6
+        value = now[0]
+        now[0] += 2.5e-6
+        return value
+
+    monkeypatch.setattr(tracer_mod, "_cpu", cpu)
+    monkeypatch.setattr(tracer_mod, "_perf", lambda: now[0])
+    t = Tracer()
+    t.enable()
+    with t.span("s"):
+        for _ in range(2 * tracer_mod.CPU_EVERY):
+            with t.stage("outer"):
+                now[0] += 30e-6
+                with t.stage("inner"):
+                    now[0] += 20e-6
+    t.disable()
+    (args,) = _args(t, "s")
+    assert args["outer_cpu_n"] == args["inner_cpu_n"] == 2
+    assert args["inner_cpu_s"] == pytest.approx(2 * 20e-6)
+    assert args["outer_cpu_s"] == pytest.approx(2 * 50e-6)
+
+
+def test_jit_compile_yields_a_compile_span():
+    import jax
+    import jax.numpy as jnp
+
+    t = Tracer()
+    t.enable()
+
+    def kbt_traced_probe(x):
+        return x * 3 + 1
+
+    t0 = time.perf_counter()
+    jax.jit(kbt_traced_probe)(jnp.arange(7)).block_until_ready()
+    t1 = time.perf_counter()
+    t.disable()
+    compiles = [e for e in t.events() if e["name"] == "compile"
+                and "kbt_traced_probe" in (e["args"]["fun"] or "")]
+    assert compiles
+    # On the tracer's own clock, inside the call that compiled.
+    start = (t0 - t._epoch) * 1e6
+    end = (t1 - t._epoch) * 1e6
+    ev = compiles[0]
+    assert start - 1e3 <= ev["ts"] and ev["ts"] + ev["dur"] <= end + 1e3
+
+
+def test_scheduler_loop_waits_are_spans():
+    """The production loop's waits carry spans: the think-time drain and
+    sleep (``loop_wait``), the arrival park (``micro_park``) and the
+    coalescing window before a micro cycle (``micro_coalesce``); so does
+    the work after each cycle (``observe_cycle``)."""
+    from kube_batch_tpu.api import PodPhase, build_resource_list
+    from kube_batch_tpu.cache import SchedulerCache
+    from kube_batch_tpu.cluster import InProcessCluster
+    from kube_batch_tpu.obs.tracer import TRACER
+    from kube_batch_tpu.scheduler import Scheduler
+    from kube_batch_tpu.utils.test_utils import (
+        build_node, build_pod, build_pod_group, build_queue)
+
+    cluster = InProcessCluster(simulate_kubelet=True)
+    cluster.create_queue(build_queue("default", 1))
+    cluster.create_node(
+        build_node("n1", build_resource_list(cpu="8", memory="16Gi")))
+    sched = Scheduler(SchedulerCache(cluster=cluster), schedule_period=0.5)
+    assert sched.micro_enabled
+    stop = threading.Event()
+    loop = threading.Thread(target=sched.run, args=(stop,), daemon=True)
+    TRACER.reset()
+    TRACER.enable()
+    try:
+        loop.start()
+        time.sleep(0.7)  # past the first cycle, into its think time
+        cluster.create_pod_group(
+            build_pod_group("pg1", namespace="ns", min_member=1))
+        cluster.create_pod(build_pod(
+            "ns", "p0", "", PodPhase.PENDING,
+            build_resource_list(cpu="500m", memory="256Mi"),
+            group_name="pg1"))
+        deadline = time.time() + 10
+        while time.time() < deadline and not sched.micro_cycles_run:
+            time.sleep(0.02)
+        time.sleep(0.6)
+    finally:
+        stop.set()
+        loop.join(timeout=10)
+        TRACER.disable()
+    assert not loop.is_alive()
+    events = TRACER.events()
+    TRACER.reset()
+    names = {e["name"] for e in events}
+    assert {"loop_wait", "micro_park", "micro_coalesce",
+            "observe_cycle"} <= names, names
+    phases = {e["args"]["phase"] for e in events if e["name"] == "loop_wait"}
+    assert "sleep" in phases
+    coalesce = [e for e in events if e["name"] == "micro_coalesce"]
+    assert all(e["args"]["window_s"] > 0 for e in coalesce)
 
 
 # --------------------------------------------------------- flight recorder
