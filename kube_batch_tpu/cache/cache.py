@@ -1690,9 +1690,12 @@ class SchedulerCache(Cache, EventHandlersMixin):
 
             self._submit_side_effect(_single_bind)
 
-    # Batched side-effect jobs are chunked so (a) a 50k-task gang doesn't
-    # monopolize one of the pool's workers for its whole serial run and
-    # (b) all workers share the bind backlog.
+    # A batch's fast binds drain in chunks: each chunk flushes its journal
+    # marks in one round trip (_journal_mark_many), and an exception that
+    # escapes one chunk leaves the others draining. Where a bind is a
+    # network call the chunks also spread over the pool's workers; where
+    # it is local CPU work (ClusterAPI.bind_is_local) one job drains them
+    # in batch order.
     _BIND_CHUNK = 1024
 
     def bind_batch(self, task_infos, on_accepted=None) -> list:
@@ -1918,9 +1921,26 @@ class SchedulerCache(Cache, EventHandlersMixin):
                 with TRACER.stage("ledgers"):
                     self._journal_mark_many(journal_seq, marks)
 
-            for start in range(0, len(binds), self._BIND_CHUNK):
-                chunk = binds[start:start + self._BIND_CHUNK]
-                self._submit_side_effect(lambda c=chunk: _do_binds(c))
+            chunks = [
+                binds[start:start + self._BIND_CHUNK]
+                for start in range(0, len(binds), self._BIND_CHUNK)
+            ]
+            if chunks and getattr(self.cluster, "bind_is_local", False):
+                def _drain_in_order():
+                    for chunk in chunks:
+                        try:
+                            with TRACER.stage("drain_chunk"):
+                                _do_binds(chunk)
+                        except Exception:
+                            logger.exception(
+                                "bind chunk of %d task(s) failed; "
+                                "draining the rest", len(chunk),
+                            )
+
+                self._submit_side_effect(_drain_in_order)
+            else:
+                for chunk in chunks:
+                    self._submit_side_effect(lambda c=chunk: _do_binds(c))
             for pod, hostname, task_snapshot in slow_binds:
                 self._submit_side_effect(
                     lambda p=pod, h=hostname, s=task_snapshot:

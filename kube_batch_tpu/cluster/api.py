@@ -76,6 +76,14 @@ class ClusterAPI:
     # single-host file lock.
     supports_lease_election = False
 
+    # True where ``bind_pod`` is local CPU work that delivers its watch
+    # events on the caller's thread (InProcessCluster): the cache then
+    # drains each bind batch in one ordered job, since more workers
+    # would only contend for the GIL and cache.mutex. False where a bind
+    # is a blocking network call (KubeCluster): the batch's chunks fan
+    # over the side-effect pool so their round trips overlap.
+    bind_is_local = False
+
     # -- volume claims (optional capability) --------------------------------
     # Default: no claim store — volumes are instantly assumable and never
     # block binds. InProcessCluster overrides with a real assume/bind
@@ -464,6 +472,9 @@ class InProcessCluster(ClusterAPI):
         self.create("PriorityClass", pc)
 
     # -- scheduler side effects ---------------------------------------------
+
+    # bind_pod below does no I/O and notifies watchers synchronously.
+    bind_is_local = True
 
     def bind_pod(self, pod: Pod, hostname: str) -> None:
         """Analog of POST pods/<name>/binding (reference cache.go:121-135)."""

@@ -10,6 +10,7 @@ from kube_batch_tpu.api import (
     build_resource_list,
 )
 from kube_batch_tpu.cache import SchedulerCache, shadow_pod_group
+from kube_batch_tpu.cluster import ClusterAPI, InProcessCluster
 from kube_batch_tpu.utils.test_utils import (
     FakeBinder,
     FakeEvictor,
@@ -30,6 +31,13 @@ def make_cache(**kwargs):
         volume_binder=FakeVolumeBinder(),
         **kwargs,
     )
+
+
+class _PooledInProcessCluster(InProcessCluster):
+    """An in-process cluster that leaves ``bind_is_local`` at the
+    ClusterAPI default, so the cache fans bind chunks over its pool."""
+
+    bind_is_local = ClusterAPI.bind_is_local
 
 
 def req_resource():
@@ -499,13 +507,16 @@ class TestBindPathStages:
     its watch event synchronously (the cache's ingest runs inside the
     bind call)."""
 
-    def test_bind_batch_chunk_spans_carry_nested_stages(self):
+    @pytest.mark.parametrize(
+        "cluster_cls", [InProcessCluster, _PooledInProcessCluster],
+        ids=["ordered", "pooled"],
+    )
+    def test_bind_batch_chunk_spans_carry_nested_stages(self, cluster_cls):
         import threading
 
-        from kube_batch_tpu.cluster import InProcessCluster
         from kube_batch_tpu.obs.tracer import TRACER
 
-        cluster = InProcessCluster(simulate_kubelet=True)
+        cluster = cluster_cls(simulate_kubelet=True)
         cluster.create_queue(build_queue("default", 1))
         cluster.create_node(
             build_node("n1", build_resource_list(cpu="8", memory="16Gi")))
@@ -541,7 +552,14 @@ class TestBindPathStages:
         finally:
             stop.set()
         chunks = [e for e in events if e["name"] == "cache_side_effect"]
-        assert sorted(e["args"]["ingest_n"] for e in chunks) == [2, 4]
+        if cluster_cls.bind_is_local:
+            # one job drains both chunks
+            (drain,) = chunks
+            assert drain["args"]["ingest_n"] == 6
+            assert drain["args"]["drain_chunk_n"] == 2
+        else:
+            assert sorted(e["args"]["ingest_n"] for e in chunks) == [2, 4]
+            assert not any("drain_chunk_n" in e["args"] for e in chunks)
         for e in chunks:
             a = e["args"]
             # the cache's ingest runs inside the cluster's bind call
@@ -554,9 +572,239 @@ class TestBindPathStages:
                 + a["event_cpu_s"] <= a["cpu_s"]
             assert a["mutex_wait_n"] == a["ingest_n"]
             assert "mutex_wait_cpu_s" not in a  # a lock wait is wall only
-            # per bind, plus the chunk's journal-mark flush
-            assert a["ledgers_n"] == a["ingest_n"] + 1
+            # per bind, plus each chunk's journal-mark flush
+            assert a["ledgers_n"] == a["ingest_n"] + a.get("drain_chunk_n", 1)
         (book,) = [e for e in events if e["name"] == "cache_bookkeeping"]
         assert book["args"]["cpu_s"] > 0 and book["args"]["ledgers_n"] == 2
         # staging hold, then the prewarm holds (one job, one node)
         assert book["args"]["mutex_wait_n"] == 3
+
+
+def _gang_of(cluster, n, claim_pod=None):
+    """Queue, one roomy node and a PodGroup of ``n`` pods ``p0``..; with
+    ``claim_pod``, one more pod of that name holding an unbound claim."""
+    cluster.create_queue(build_queue("default", 1))
+    cluster.create_node(build_node("n1", build_resource_list(
+        cpu="16", memory="32Gi", pods=110)))
+    cluster.create_pod_group(
+        build_pod_group("pg1", namespace="ns", min_member=1))
+    for i in range(n):
+        cluster.create_pod(build_pod(
+            "ns", f"p{i}", "", PodPhase.PENDING,
+            build_resource_list(cpu="500m", memory="256Mi"),
+            group_name="pg1"))
+    if claim_pod is not None:
+        cluster.create_claim("ns", "c1", bound=False)
+        pod = build_pod(
+            "ns", claim_pod, "", PodPhase.PENDING,
+            build_resource_list(cpu="500m", memory="256Mi"),
+            group_name="pg1")
+        pod.spec.volume_claims = ["c1"]
+        cluster.create_pod(pod)
+
+
+def _bind_infos(cache, names):
+    """Session-side copies of the named tasks of ns/pg1, placed on n1, in
+    the order given; a task with claims is not volume-ready."""
+    with cache.mutex:
+        by_name = {t.name: t for t in cache.jobs["ns/pg1"].tasks.values()}
+    infos = []
+    for name in names:
+        info = by_name[name].clone()
+        info.node_name = "n1"
+        info.volume_ready = not info.pod.spec.volume_claims
+        infos.append(info)
+    return infos
+
+
+def _count_submits(cache):
+    """Record the ``bookkeeping`` flag of every side-effect job the cache
+    submits from now on."""
+    submitted = []
+    submit = cache._submit_side_effect
+
+    def spy(fn, bookkeeping=False):
+        submitted.append(bookkeeping)
+        submit(fn, bookkeeping)
+
+    cache._submit_side_effect = spy
+    return submitted
+
+
+class TestOrderedBindDrain:
+    """On a cluster whose binds are local CPU work, one side-effect job
+    drains a batch's fast binds chunk by chunk, in batch order."""
+
+    def test_fast_binds_drain_in_one_job_in_batch_order(self):
+        import threading
+        import time
+
+        from kube_batch_tpu.cache.cache import DefaultVolumeBinder
+
+        cluster = InProcessCluster(simulate_kubelet=True)
+        _gang_of(cluster, 7, claim_pod="pv")
+        cache = SchedulerCache(
+            cluster=cluster,
+            volume_binder=DefaultVolumeBinder(cluster, bind_timeout=30.0),
+        )
+        cache._BIND_CHUNK = 2  # 7 fast binds: 4 chunks
+        cache.start_ingest()
+        binds = []  # (pod name, rv, thread) per bind event, as delivered
+
+        def on_event(kind, event_type, obj, rv):
+            if kind == "Pod" and obj.spec.node_name:
+                binds.append(
+                    (obj.metadata.name, rv, threading.get_ident()))
+
+        cluster.add_watch(on_event)
+        # the claim-bearing pod sits mid-batch
+        order = ["p5", "p0", "pv", "p3", "p6", "p1", "p4", "p2"]
+        fast = [name for name in order if name != "pv"]
+        submitted = _count_submits(cache)
+        try:
+            cache.bind_batch(_bind_infos(cache, order))
+            # every fast bind lands while the claim still waits
+            deadline = time.monotonic() + 10
+            while len(binds) < len(fast) and time.monotonic() < deadline:
+                time.sleep(0.01)
+            assert [name for name, _, _ in binds] == fast
+            assert cluster.get_pod("ns", "pv").spec.node_name == ""
+            cluster.set_claim_bound("ns", "c1")
+            assert cache.wait_for_side_effects(timeout=10)
+        finally:
+            cache.shutdown()
+        # the bookkeeping job, ONE drain job, the claim's own job
+        assert submitted == [True, False, False]
+        fast_binds = binds[:len(fast)]
+        assert len({tid for _, _, tid in fast_binds}) == 1
+        rvs = [rv for _, rv, _ in fast_binds]
+        assert rvs == sorted(rvs)
+        # every pod bound once, the claim's last
+        assert [name for name, _, _ in binds] == fast + ["pv"]
+        for name in order:
+            assert cluster.get_pod("ns", name).spec.node_name == "n1"
+        assert cluster.list_bind_intents() == []
+
+    def test_exception_out_of_one_chunk_leaves_later_chunks_draining(
+            self, caplog):
+        import logging
+
+        cluster = InProcessCluster(simulate_kubelet=True)
+        _gang_of(cluster, 6)
+        cache = SchedulerCache(cluster=cluster)
+        cache._BIND_CHUNK = 2  # chunks (p0 p1) (p2 p3) (p4 p5)
+        cache.start_ingest()
+        bind_one = cache._bind_side_effect
+
+        def flaky(pod, *args, **kwargs):
+            if pod.metadata.name == "p2":
+                raise RuntimeError("injected escape from a chunk")
+            return bind_one(pod, *args, **kwargs)
+
+        cache._bind_side_effect = flaky
+        try:
+            with caplog.at_level(logging.ERROR):
+                cache.bind_batch(_bind_infos(
+                    cache, [f"p{i}" for i in range(6)]))
+                assert cache.wait_for_side_effects(timeout=10)
+        finally:
+            cache.shutdown()
+        assert any(
+            "bind chunk" in r.getMessage() and r.exc_info
+            for r in caplog.records
+        )
+        node_of = {
+            f"p{i}": cluster.get_pod("ns", f"p{i}").spec.node_name
+            for i in range(6)
+        }
+        # the failed chunk stops at p2; the chunk after it still binds
+        assert node_of == {"p0": "n1", "p1": "n1", "p2": "", "p3": "",
+                           "p4": "n1", "p5": "n1"}
+        assert cache._inflight == 0
+
+
+class TestPooledBindDrain:
+    """Backends that leave ``bind_is_local`` at its default keep the
+    pooled fan-out: one side-effect job per chunk."""
+
+    @staticmethod
+    def _kube():
+        from kube_batch_tpu.cluster import KubeCluster, KubeConfig
+        from kube_batch_tpu.utils.fake_kube import (
+            FakeKube,
+            node_doc,
+            pod_doc,
+        )
+
+        fake = FakeKube()
+        fake.create("Queue", {
+            "apiVersion": "scheduling.incubator.k8s.io/v1alpha1",
+            "kind": "Queue", "metadata": {"name": "default"},
+            "spec": {"weight": 1},
+        })
+        fake.create("PodGroup", {
+            "apiVersion": "scheduling.incubator.k8s.io/v1alpha1",
+            "kind": "PodGroup",
+            "metadata": {"name": "pg1", "namespace": "ns"},
+            "spec": {"minMember": 1, "queue": "default"},
+        })
+        fake.create("Node", node_doc("n1", cpu="16"))
+        for i in range(6):
+            fake.create("Pod", pod_doc(f"p{i}", ns="ns", group="pg1"))
+        cluster = KubeCluster(KubeConfig(fake.url), reconnect_delay=0.05)
+
+        def bound():
+            return sorted(pod for pod, _ in fake.bindings)
+
+        def close():
+            cluster.stop()
+            fake.close()
+
+        return cluster, bound, close
+
+    @staticmethod
+    def _failover():
+        from kube_batch_tpu.sim.failover import SimClusterEndpoint
+
+        inner = InProcessCluster(simulate_kubelet=True)
+        _gang_of(inner, 6)
+
+        def bound():
+            return sorted(
+                f"ns/{p.metadata.name}" for p in inner.list_objects("Pod")
+                if p.spec.node_name)
+
+        return SimClusterEndpoint(inner, seed=0), bound, lambda: None
+
+    @pytest.mark.parametrize("backend", ["kube", "failover"])
+    def test_chunks_fan_over_the_pool(self, backend):
+        import threading
+        import time
+
+        cluster, bound, close = getattr(self, f"_{backend}")()
+        assert type(cluster).bind_is_local is ClusterAPI.bind_is_local
+        assert ClusterAPI.bind_is_local is False
+        cache = SchedulerCache(cluster=cluster)
+        cache._BIND_CHUNK = 4
+        stop = threading.Event()
+        try:
+            cache.run(stop)
+            assert cache.wait_for_cache_sync(stop)
+            deadline = time.monotonic() + 10
+            while time.monotonic() < deadline:
+                with cache.mutex:
+                    job = cache.jobs.get("ns/pg1")
+                    if job is not None and len(job.tasks) == 6:
+                        break
+                time.sleep(0.02)
+            submitted = _count_submits(cache)
+            cache.bind_batch(_bind_infos(
+                cache, [f"p{i}" for i in range(6)]))
+            assert cache.wait_for_side_effects(timeout=10)
+            # the bookkeeping job, then one job per chunk of 4
+            assert submitted == [True, False, False]
+            assert bound() == [f"ns/p{i}" for i in range(6)]
+        finally:
+            stop.set()
+            cache.shutdown()
+            close()
