@@ -816,12 +816,11 @@ class AllocateTpuAction(Action):
             assigned, handle = self._solve_ladder(
                 ssn, rungs, inputs, ctx, handle, budget, ladder
             )
+        t_solved = time.perf_counter()
         ssn.register_inflight_solve(None)
         rounds, backend = handle.rounds, handle.backend
         metrics.update_solver_cycle(rounds, backend)
-        last_stats["solve_block_ms"] = (
-            time.perf_counter() - t_block
-        ) * 1e3
+        last_stats["solve_block_ms"] = (t_solved - t_block) * 1e3
         _record_phase("solve", (time.perf_counter() - t0) * 1e3)
         last_stats.update(backend=backend, rounds=rounds)
         last_stats["solve_ladder"] = ladder
@@ -910,6 +909,16 @@ class AllocateTpuAction(Action):
             ):
                 if key in spmd_mod.last_commit_stats:
                     last_stats[key] = spmd_mod.last_commit_stats[key]
+            if TRACER.enabled:
+                # The sharded solve's block wait, carrying the commit
+                # collective's counters: bytes a shard receives per
+                # flat round, and the rounds this solve took.
+                TRACER.complete(
+                    "shard_commit", t_block, t_solved,
+                    commit_bytes_per_round=spmd_mod.last_commit_stats.get(
+                        "commit_bytes_per_round"),
+                    reconcile_rounds=handle.reconcile_rounds,
+                )
         # Which path produced the candidate slabs (device-resident
         # selection vs labeled host fallback) — tensorize stats carry
         # the label; the device counter is incremented at the source.

@@ -420,6 +420,7 @@ def _sparse_sharded_step(inputs, mesh: Mesh, mode: str, max_rounds,
     """(step, device_inputs) for the task-sharded sparse solve: pad
     the task axis (and node axis for two-level) to the mesh multiple,
     device_put replicated, hand back the cached jitted step."""
+    from ..obs.tracer import span
     from .spmd import (
         _spmd_sparse_step,
         note_commit_stats,
@@ -427,26 +428,27 @@ def _sparse_sharded_step(inputs, mesh: Mesh, mode: str, max_rounds,
     )
 
     note_commit_stats(inputs)
-    if not isinstance(inputs, PackedInputs):
-        inputs = pad_tasks(inputs, mesh.size)
-        if mode == "two-level":
-            inputs = pad_nodes(inputs, mesh.size)
-    elif _task_count(inputs) % mesh.size or (
-        mode == "two-level" and _node_count(inputs) % mesh.size
-    ):
-        # A silent mis-split would simply never solve the remainder
-        # rows; refuse loudly (solve_sharded routes ragged packed
-        # bundles to the single-device jit before ever getting here).
-        raise ValueError(
-            f"sparse sharded solve needs task{'/node' if mode == 'two-level' else ''} "
-            f"axes divisible by the mesh size {mesh.size}"
+    with span("shard_put", shard_mode=mode, shards=mesh.size):
+        if not isinstance(inputs, PackedInputs):
+            inputs = pad_tasks(inputs, mesh.size)
+            if mode == "two-level":
+                inputs = pad_nodes(inputs, mesh.size)
+        elif _task_count(inputs) % mesh.size or (
+            mode == "two-level" and _node_count(inputs) % mesh.size
+        ):
+            # A silent mis-split would simply never solve the remainder
+            # rows; refuse loudly (solve_sharded routes ragged packed
+            # bundles to the single-device jit before ever getting here).
+            raise ValueError(
+                f"sparse sharded solve needs task{'/node' if mode == 'two-level' else ''} "
+                f"axes divisible by the mesh size {mesh.size}"
+            )
+        inputs = jax.device_put(
+            inputs, sparse_spmd_shardings_for(inputs, mesh)
         )
-    inputs = jax.device_put(
-        inputs, sparse_spmd_shardings_for(inputs, mesh)
-    )
-    step = _spmd_sparse_step(
-        mesh, max_rounds, tail_bucket, mode == "two-level"
-    )
+        step = _spmd_sparse_step(
+            mesh, max_rounds, tail_bucket, mode == "two-level"
+        )
     return step, inputs
 
 

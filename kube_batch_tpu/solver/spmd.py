@@ -1305,7 +1305,9 @@ def _spmd_sparse_step(mesh: Mesh, max_rounds, tail_bucket, two_level):
         if any(int(perm[i]) != i for i in range(len(perm))):
             rack_of_shard = tuple(int(r) for r in perm)
 
-    def run(inputs: Any) -> SolverResult:
+    # Named for what it is: its XLA module, ``jit_solve_sparse_sharded``,
+    # is what a device trace finds among the solve's modules.
+    def solve_sparse_sharded(inputs: Any) -> SolverResult:
         if isinstance(inputs, PackedInputs):
             inputs = inputs.unpack()  # inside jit: free slicing
         in_specs = SolverInputs(**{
@@ -1334,7 +1336,7 @@ def _spmd_sparse_step(mesh: Mesh, max_rounds, tail_bucket, two_level):
 
     import weakref
 
-    step = jax.jit(run)
+    step = jax.jit(solve_sparse_sharded)
     _jitted_steps.append(weakref.ref(step))
     return step
 

@@ -452,3 +452,48 @@ class TestShardedActionEndToEnd:
         assert dict(atpu.last_stats).get("sparse_sharded_engaged") is False
         assert sharded_binds == single_binds
         c2.shutdown()
+
+    @pytest.mark.parametrize("mode,traced", [
+        ("flat", True), ("off", True), ("flat", False),
+    ])
+    def test_shard_spans_and_commit_counters(self, mesh, monkeypatch, mode,
+                                             traced):
+        """A traced sharded cycle records ``shard_put`` (mode, shards) and
+        ``shard_commit`` (the commit collective's counters); a
+        single-device cycle records neither; with tracing off nothing is
+        recorded."""
+        from kube_batch_tpu.actions import allocate_tpu as atpu
+        from kube_batch_tpu.obs.tracer import TRACER
+        from kube_batch_tpu.solver import spmd
+
+        monkeypatch.setenv("KBT_SPARSE_SHARD_MODE", mode)
+        TRACER.reset()
+        if traced:
+            TRACER.enable()
+        try:
+            c = self._build(monkeypatch)
+        finally:
+            TRACER.disable()
+        recorded = list(TRACER._events)
+        TRACER.reset()
+        c.shutdown()
+        stats = dict(atpu.last_stats)
+        args = {}
+        for rec in recorded:
+            args.setdefault(rec[0], []).append(rec[7])
+        if not traced:
+            assert recorded == []
+        elif mode == "off":
+            assert stats.get("sparse_sharded_engaged") is False
+            assert "shard_put" not in args and "shard_commit" not in args
+        else:
+            assert args["shard_put"] == [
+                {"shard_mode": "flat", "shards": mesh.size}
+            ]
+            assert args["shard_commit"] == [{
+                "commit_bytes_per_round":
+                    spmd.last_commit_stats["commit_bytes_per_round"],
+                "reconcile_rounds": stats["sparse_reconcile_rounds"],
+            }]
+            assert stats["sparse_reconcile_rounds"] >= 1
+
