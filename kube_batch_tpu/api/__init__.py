@@ -40,6 +40,7 @@ from .objects import (
 from .pod_info import (
     get_pod_resource_request,
     get_pod_resource_without_init_containers,
+    same_requests,
 )
 from .serving import (
     CAPACITY_RESERVED,

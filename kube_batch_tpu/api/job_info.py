@@ -50,6 +50,10 @@ def get_job_id(pod: Pod) -> JobID:
     return ""
 
 
+def _pod_priority(pod: Pod) -> int:
+    return pod.spec.priority if pod.spec.priority is not None else 1
+
+
 class TaskInfo:
     """All scheduling info about one task (reference job_info.go:36-54)."""
 
@@ -74,9 +78,7 @@ class TaskInfo:
         self.namespace = pod.namespace
         self.node_name = pod.spec.node_name
         self.status = get_task_status(pod)
-        self.priority: int = (
-            pod.spec.priority if pod.spec.priority is not None else 1
-        )
+        self.priority: int = _pod_priority(pod)
         self.volume_ready = False
         self.pod = pod
         # Frozen: clones share these (see TaskInfo.clone / FrozenResource).
@@ -211,6 +213,9 @@ class JobInfo:
         self.total_request.add(ti.resreq)
         if allocated_status(ti.status):
             self.allocated.add(ti.resreq)
+        self._classify(ti.pod)
+
+    def _classify(self, pod: Pod) -> None:
         # Serving-class opt-in: the first member carrying the
         # workload-class annotation classifies the job (one dict get on
         # the already-classified hot path; members of one job share
@@ -218,13 +223,13 @@ class JobInfo:
         if (
             self.slo is None
             and self.workload_class == WORKLOAD_CLASS_BATCH
-            and ti.pod.metadata.annotations.get(
+            and pod.metadata.annotations.get(
                 WORKLOAD_CLASS_ANNOTATION_KEY
             ) == WORKLOAD_CLASS_SERVING
         ):
             self._ver += 1
             self.workload_class = WORKLOAD_CLASS_SERVING
-            self.slo = parse_serving_slo(ti.pod.metadata.annotations)
+            self.slo = parse_serving_slo(pod.metadata.annotations)
 
     def delete_task_info(self, ti: TaskInfo) -> None:
         """reference job_info.go:271-287"""
@@ -273,6 +278,19 @@ class JobInfo:
             self.allocated.add(task.resreq)
         task.status = status
         self._add_task_index(task)
+
+    def confirm_task(self, task: TaskInfo, pod: Pod,
+                     status: TaskStatus) -> None:
+        """Point the stored ``task`` at ``pod``, a newer copy of its pod
+        with the same requests, and move it to ``status``; both its status
+        and ``status`` are allocated. The state a delete_task_info of
+        ``task`` and an add_task_info of a fresh ``TaskInfo(pod)`` leave,
+        except that the task keeps its object and its place in ``tasks``."""
+        self.update_task_status(task, status)
+        task.pod = pod
+        task.priority = _pod_priority(pod)
+        task.volume_ready = False
+        self._classify(pod)
 
     def update_tasks_status(
         self,

@@ -299,6 +299,21 @@ class NodeInfo:
         self.remove_task(ti)
         self.add_task(ti)
 
+    def refresh_task(self, ti: TaskInfo) -> None:
+        """Replace the entry of ``ti`` with a fresh clone, for a change
+        that moves no accounting: the same requests, and the old and the
+        new status both on add_task's default branch (neither Releasing
+        nor Pipelined). The state update_task leaves, without the
+        idle/used round trip; the entry keeps its place in ``tasks``."""
+        key = pod_key(ti.pod)
+        if key not in self.tasks:
+            raise KeyError(
+                f"failed to find task <{ti.namespace}/{ti.name}> "
+                f"on host <{self.name}>"
+            )
+        self._ver += 1
+        self.tasks[key] = ti.clone()
+
     def clone(self) -> "NodeInfo":
         """Deep copy for the per-cycle snapshot (reference
         node_info.go:92-100). The reference rebuilds accounting by

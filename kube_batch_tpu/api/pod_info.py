@@ -27,3 +27,15 @@ def get_pod_resource_without_init_containers(pod: Pod) -> Resource:
     for c in pod.spec.containers:
         result.add(Resource.from_resource_list(c.requests))
     return result
+
+
+def same_requests(a: Pod, b: Pod) -> bool:
+    """True iff two copies of a pod ask for the same resources: the request
+    maps of their containers and of their init containers equal by value,
+    in order. Compares the quantity strings as given, so it parses none."""
+    return (
+        [c.requests for c in a.spec.containers]
+        == [c.requests for c in b.spec.containers]
+        and [c.requests for c in a.spec.init_containers]
+        == [c.requests for c in b.spec.init_containers]
+    )

@@ -24,7 +24,14 @@ from ..api import (
     QueueInfo,
     TaskInfo,
     TaskStatus,
+    allocated_status,
+    get_controller_uid,
+    get_job_id,
+    get_task_status,
+    pod_key,
+    same_requests,
 )
+from ..obs.tracer import TRACER
 from .util import create_shadow_pod_group, job_terminated
 
 logger = logging.getLogger(__name__)
@@ -286,11 +293,54 @@ class EventHandlersMixin:
             and old_ti.init_resreq == new_ti.init_resreq
         )
 
+    def _echo_in_place(self, pod: Pod) -> bool:
+        """Apply in place the echo of a placement the cache staged: the
+        API server's confirmation of a bind, or the kubelet's Running
+        flip, of a task the cache holds on the same node, allocated
+        before and after, asking for the same resources. The full path's
+        delete plus re-add (three TaskInfo builds, each parsing every
+        quantity) would leave the same state: the stored task moved to
+        its new status index, a fresh node clone, both versions bumped,
+        the names stamped narrow (see _allocated_status_flip). Returns
+        False, having touched nothing, for any other event. The caller
+        holds the mutex.
+
+        The requests are the stored pod's when ``pod`` IS the stored pod
+        (the in-process cluster delivers the object it mutates; a pod's
+        container resources are immutable, as pod_key's memo relies on
+        its uid and name being) or when they are equal by value (a watch
+        that delivers a new object each time)."""
+        job_key = get_job_id(pod) or get_controller_uid(pod) or pod.uid
+        job = self.jobs.get(job_key)
+        if job is None:
+            return False
+        stored = job.tasks.get(pod.uid)
+        if stored is None:
+            return False
+        node_name = stored.node_name
+        if not node_name or node_name != pod.spec.node_name:
+            return False
+        status = get_task_status(pod)
+        if not (allocated_status(stored.status) and allocated_status(status)):
+            return False
+        if pod is not stored.pod and not same_requests(pod, stored.pod):
+            return False
+        node = self.nodes.get(node_name)
+        if node is None or pod_key(pod) not in node.tasks:
+            return False
+        with TRACER.stage("echo_inplace"):
+            job.confirm_task(stored, pod, status)
+            node.refresh_task(stored)
+            self._stamp_dirty_alloc(job_key, node_name)
+        return True
+
     def update_pod(self, old_pod: Pod, new_pod: Pod) -> None:
         """reference event_handlers.go:128-133 (deletePod + addPod)"""
         if not self._accept_pod(new_pod):
             return
         with self.mutex:
+            if self._echo_in_place(new_pod):
+                return
             old_ti = self._stored_task(TaskInfo(old_pod))
             narrow = self._allocated_status_flip(old_ti, TaskInfo(new_pod))
             job_key = self._effective_job_key(old_ti)

@@ -808,3 +808,254 @@ class TestPooledBindDrain:
             stop.set()
             cache.shutdown()
             close()
+
+
+def _res(r):
+    return (r.milli_cpu, r.memory, r.scalar_resources, r.max_task_num)
+
+
+def _task_state(t, pod):
+    return (t.status, t.node_name, t.job, t.priority, t.volume_ready,
+            _res(t.resreq), _res(t.init_resreq), t.pod is pod)
+
+
+def _cache_state(cache, pods):
+    """What the cache's pod ingest can leave behind, in no order: each
+    job's tasks, status index and aggregates, each node's accounting and
+    task clones, and the full and narrow dirty sets. ``pods`` maps a uid
+    to the object its tasks should point at."""
+    with cache.mutex:
+        jobs = {
+            uid: (
+                {s: sorted(b) for s, b in job.task_status_index.items()},
+                _res(job.allocated), _res(job.total_request),
+                job.workload_class,
+                {u: _task_state(t, pods.get(u)) for u, t in job.tasks.items()},
+            )
+            for uid, job in cache.jobs.items()
+        }
+        nodes = {
+            name: (
+                _res(node.idle), _res(node.used), _res(node.releasing),
+                node.state.phase, node.state.reason,
+                {k: _task_state(t, pods.get(t.uid))
+                 for k, t in node.tasks.items()},
+            )
+            for name, node in cache.nodes.items()
+        }
+        dirty = (
+            sorted(cache._dirty_jobs), sorted(cache._dirty_nodes),
+            sorted(cache._dirty_jobs_alloc - cache._dirty_jobs),
+            sorted(cache._dirty_nodes_alloc - cache._dirty_nodes),
+        )
+    return jobs, nodes, dirty
+
+
+def _versions(cache):
+    with cache.mutex:
+        return ({u: j._ver for u, j in cache.jobs.items()},
+                {n: node._ver for n, node in cache.nodes.items()})
+
+
+def _moved(before, after):
+    return [{k for k, v in a.items() if before[i].get(k) != v}
+            for i, a in enumerate(after)]
+
+
+def _no_echo_in_place(cache):
+    """Send every pod MODIFIED down the full delete plus re-add path."""
+    cache._echo_in_place = lambda pod: False
+
+
+def _spy_echo_in_place(cache):
+    """Record what the in-place path answers for each pod MODIFIED."""
+    answers = []
+    echo = cache._echo_in_place
+
+    def spy(pod):
+        answers.append(echo(pod))
+        return answers[-1]
+
+    cache._echo_in_place = spy
+    return answers
+
+
+class TestEchoInPlace:
+    """The echo of a placement the cache staged (the bind confirmation, the
+    kubelet's Running flip) is applied to the stored task in place, and
+    leaves the state the full delete plus re-add leaves."""
+
+    KUBELET = {
+        "running": dict(simulate_kubelet=True),
+        "bound": dict(simulate_kubelet=False),
+        "bound-then-running": dict(simulate_kubelet=True,
+                                   kubelet_delay=0.01),
+    }
+
+    def _drain(self, kubelet, full_path):
+        """Bind a gang of six through bind_batch's ordered drain and wait
+        for every echo; returns the state, which jobs and nodes bumped
+        their versions, and whether each stored task object was kept."""
+        import time
+
+        cluster = InProcessCluster(**self.KUBELET[kubelet])
+        _gang_of(cluster, 6)
+        cache = SchedulerCache(cluster=cluster)
+        cache._BIND_CHUNK = 4
+        if full_path:
+            _no_echo_in_place(cache)
+        cache.start_ingest()
+        want = (TaskStatus.BOUND if kubelet == "bound"
+                else TaskStatus.RUNNING)
+        try:
+            with cache.mutex:
+                stored = dict(cache.jobs["ns/pg1"].tasks)
+            cache.snapshot()  # absorb the ingest's stamps
+            before = _versions(cache)
+            cache.bind_batch(_bind_infos(cache, [f"p{i}" for i in range(6)]))
+            assert cache.wait_for_side_effects(timeout=10)
+            deadline = time.monotonic() + 10
+            while time.monotonic() < deadline:
+                with cache.mutex:
+                    tasks = cache.jobs["ns/pg1"].tasks
+                    if all(t.status == want for t in tasks.values()):
+                        break
+                time.sleep(0.01)
+            pods = {p.uid: p for p in cluster.list_objects("Pod")}
+            state = _cache_state(cache, pods)
+            moved = _moved(before, _versions(cache))
+            with cache.mutex:
+                kept = {u: cache.jobs["ns/pg1"].tasks[u] is t
+                        for u, t in stored.items()}
+        finally:
+            cache.shutdown()
+        return state, moved, kept
+
+    @pytest.mark.parametrize("kubelet", sorted(KUBELET))
+    def test_bind_drain_echo_leaves_the_full_path_state(self, kubelet):
+        state, moved, kept = self._drain(kubelet, full_path=False)
+        ref_state, ref_moved, ref_kept = self._drain(kubelet, full_path=True)
+        assert state == ref_state
+        jobs, nodes, _ = state
+        assert all(t[0] == (TaskStatus.BOUND if kubelet == "bound"
+                            else TaskStatus.RUNNING) and t[-1]
+                   for t in jobs["ns/pg1"][4].values())
+        assert nodes["n1"][1][0] == 6 * 500  # used cpu, counted once
+        assert moved == ref_moved == [{"ns/pg1"}, {"n1"}]
+        assert all(kept.values()) and len(kept) == 6
+        assert not any(ref_kept.values())
+
+    def test_echo_counts_on_the_drain_span(self):
+        from kube_batch_tpu.obs.tracer import TRACER
+
+        cluster = InProcessCluster(simulate_kubelet=True)
+        _gang_of(cluster, 6)
+        cache = SchedulerCache(cluster=cluster)
+        cache._BIND_CHUNK = 4
+        cache.start_ingest()
+        TRACER.reset()
+        TRACER.enable()
+        try:
+            with TRACER.span("cycle"):
+                cache.bind_batch(
+                    _bind_infos(cache, [f"p{i}" for i in range(6)]))
+            assert cache.wait_for_side_effects(timeout=10)
+        finally:
+            TRACER.disable()
+            cache.shutdown()
+        events = TRACER.events()
+        TRACER.reset()
+        (drain,) = [e for e in events if e["name"] == "cache_side_effect"]
+        assert drain["args"]["echo_inplace_n"] == drain["args"]["ingest_n"] \
+            == 6
+        assert drain["args"]["echo_inplace_s"] <= drain["args"]["ingest_s"]
+
+    @staticmethod
+    def _pod(name, node, phase="Running", cpu="500m", **kw):
+        return build_pod("ns", name, node, phase,
+                         build_resource_list(cpu=cpu, memory="256Mi"),
+                         group_name="pg1", **kw)
+
+    def _cache(self, full_path):
+        """p0 bound to n1 (Bound), p1 Running on n1, p2 pending, their
+        volumes ready; n2 empty."""
+        c = make_cache()
+        for name in ("n1", "n2"):
+            c.add_node(build_node(name, build_resource_list(
+                cpu="8", memory="16Gi", pods=110)))
+        c.add_pod_group(build_pod_group("pg1", namespace="ns"))
+        c.add_pod(self._pod("p0", "n1", phase=PodPhase.PENDING))
+        c.add_pod(self._pod("p1", "n1"))
+        c.add_pod(self._pod("p2", "", phase=PodPhase.PENDING))
+        for task in c.jobs["ns/pg1"].tasks.values():
+            task.volume_ready = True  # a fresh TaskInfo says False
+        c.snapshot()  # absorb the ingest's stamps
+        if full_path:
+            _no_echo_in_place(c)
+        return c
+
+    def _apply(self, make_event, full_path):
+        c = self._cache(full_path)
+        answers = [] if full_path else _spy_echo_in_place(c)
+        with c.mutex:
+            stored = {u: t for u, t in c.jobs["ns/pg1"].tasks.items()}
+        pod = make_event()
+        before = _versions(c)
+        c.update_pod(pod, pod)
+        with c.mutex:
+            kept = c.jobs["ns/pg1"].tasks.get(pod.uid) is stored.get(pod.uid)
+        return (_cache_state(c, {pod.uid: pod}),
+                _moved(before, _versions(c)), answers, kept)
+
+    def test_new_object_with_equal_requests_takes_the_in_place_path(self):
+        """A watch that delivers a new object each time: the Running flip
+        of the bound p0, as a copy."""
+        event = lambda: self._pod("p0", "n1")  # noqa: E731
+        state, moved, answers, kept = self._apply(event, full_path=False)
+        ref_state, ref_moved, _, _ = self._apply(event, full_path=True)
+        assert answers == [True] and kept
+        assert state == ref_state
+        assert moved == ref_moved == [{"ns/pg1"}, {"n1"}]
+        jobs, _, dirty = state
+        assert jobs["ns/pg1"][4]["ns-p0"][0] == TaskStatus.RUNNING
+        assert dirty == ([], [], ["ns/pg1"], ["n1"])
+
+    def _deleting(self):
+        pod = self._pod("p1", "n1")
+        pod.metadata.deletion_timestamp = 1.0
+        return pod
+
+    FULL_PATH = {
+        "other-node": lambda self: self._pod("p0", "n2"),
+        "resized": lambda self: self._pod("p0", "n1", cpu="1000m"),
+        "releasing": _deleting,
+        "succeeded": lambda self: self._pod("p1", "n1",
+                                            phase=PodPhase.SUCCEEDED),
+        "failed": lambda self: self._pod("p1", "n1", phase=PodPhase.FAILED),
+        "unknown-uid": lambda self: self._pod("p9", "n1"),
+        "bound-elsewhere": lambda self: self._pod("p2", "n1"),
+    }
+
+    @pytest.mark.parametrize("case", sorted(FULL_PATH))
+    def test_other_events_take_the_full_path(self, case):
+        event = lambda: self.FULL_PATH[case](self)  # noqa: E731
+        state, moved, answers, kept = self._apply(event, full_path=False)
+        ref_state, ref_moved, _, _ = self._apply(event, full_path=True)
+        assert answers == [False]
+        assert state == ref_state and moved == ref_moved
+        if case != "unknown-uid":
+            assert not kept  # a fresh TaskInfo replaced the stored one
+        jobs, nodes, dirty = state
+        where = {name: set(n[5]) for name, n in nodes.items()}
+        expect = {
+            "other-node": {"n1": {"ns-p1"}, "n2": {"ns-p0"}},
+            "resized": {"n1": {"ns-p0", "ns-p1"}, "n2": set()},
+            "releasing": {"n1": {"ns-p0", "ns-p1"}, "n2": set()},
+            "succeeded": {"n1": {"ns-p0"}, "n2": set()},
+            "failed": {"n1": {"ns-p0"}, "n2": set()},
+            "unknown-uid": {"n1": {"ns-p0", "ns-p1", "ns-p9"}, "n2": set()},
+            "bound-elsewhere": {"n1": {"ns-p0", "ns-p1", "ns-p2"},
+                                "n2": set()},
+        }[case]
+        assert where == expect
+        assert "ns/pg1" in dirty[0]  # stamped full
