@@ -61,8 +61,10 @@ from kube_batch_tpu.cache import SchedulerCache
 from kube_batch_tpu.framework import close_session, get_action, open_session
 from kube_batch_tpu.solver import (
     default_mesh,
+    plan_for,
     sharded_step,
     solve_jit,
+    solve_plan,
     solve_sharded,
     tensorize,
 )
@@ -214,9 +216,8 @@ def bench_tpu(cfg, seed=0, repeats=3):
     # arrays, so the loop isolates the solve itself.
     import jax
 
-    mesh = default_mesh()
-    if mesh is not None:
-        step, dev_inputs = sharded_step(inputs, mesh)
+    if ctx.plan.mode != "single":
+        step, dev_inputs = sharded_step(inputs, ctx.plan)
     else:
         step, dev_inputs = solve_jit, inputs
     result = jax.block_until_ready(step(dev_inputs))
@@ -1095,7 +1096,6 @@ def bench_sparse_scale(shape="200000x20000", seed=0, wide_mix=False):
     comparable."""
     from kube_batch_tpu.solver.kernels import SolverInputs
     from kube_batch_tpu.solver.masks import CombinedMask
-    from kube_batch_tpu.solver.topk import topk_config
 
     T, N = (int(x) for x in shape.lower().split("x"))
     rng = np.random.RandomState(seed)
@@ -1124,8 +1124,8 @@ def bench_sparse_scale(shape="200000x20000", seed=0, wide_mix=False):
         pair_idx=np.zeros((0,), np.int32),
         pair_rows=np.zeros((0, N), bool),
     )
-    tk = topk_config(T, N)
-    k = tk.k if tk.enabled else 64
+    plan = solve_plan(T, N, None)
+    k = plan.k if plan.sparse else 64
     sel, cs = _select_scale_ab(mask, task_req, node_idle, eps, k, seed)
     out = {
         "shape": f"{T}x{N}",
@@ -1832,7 +1832,9 @@ def main():
 
         with jax.profiler.trace(args.profile):
             jax.block_until_ready(
-                solve_sharded(tpu["inputs"], default_mesh())
+                solve_sharded(
+                    tpu["inputs"], plan_for(tpu["inputs"], default_mesh())
+                )
             )
 
     # vs_baseline: measured NATIVE reference loop at the headline scale

@@ -25,6 +25,7 @@ from __future__ import annotations
 import logging
 import os
 import time
+from typing import Optional
 
 import numpy as np
 
@@ -33,7 +34,7 @@ from ..api import Resource
 from ..framework import Action, register_action
 from ..obs import RECORDER, span
 from ..obs.tracer import TRACER
-from ..solver import solve_sharded, tensorize
+from ..solver import SolvePlan, solve_sharded, tensorize
 from ..utils.lockdebug import wrap_lock
 from ..utils.scheduler_helper import prioritize_nodes, select_best_node
 
@@ -175,9 +176,11 @@ class AsyncSolveHandle:
         self._fault_hook = None
 
     @classmethod
-    def launch(cls, inputs, use_native: bool, max_rounds: int,
+    def launch(cls, inputs, plan: Optional[SolvePlan], max_rounds: int,
                fault_hook=None) -> "AsyncSolveHandle":
-        if use_native:
+        """Dispatch ``inputs`` under the solve ``plan``
+        (solver/plan.py); ``plan`` None runs the native floor."""
+        if plan is None:
             handle = cls("native")
             from ..native import solve_native
 
@@ -199,11 +202,10 @@ class AsyncSolveHandle:
         # the fetch-side materialization, where a raise/hang lands
         # exactly where a real device fault would.
         handle._fault_hook = fault_hook
-        # solve_sharded shards the node axis over all visible devices
-        # (the multi-chip scale path) and falls back to the cached
-        # single-device jit when only one device exists. The call
-        # returns the moment dispatch completes.
-        handle._result = solve_sharded(inputs, max_rounds=max_rounds)
+        # solve_sharded carries out the plan tensorize built: the cached
+        # single-device jit, or a shard_map step over the mesh. The
+        # call returns the moment dispatch completes.
+        handle._result = solve_sharded(inputs, plan, max_rounds=max_rounds)
         return handle
 
     def done(self) -> bool:
@@ -381,15 +383,20 @@ class AllocateTpuAction(Action):
         """One rung's dispatch. ``native`` consumes the host-side
         :class:`SolverInputs` that every tensorize (device or not)
         leaves on the context — the floor must never touch a device
-        that just failed, not even to read the fallback bundle."""
+        that just failed, not even to read the fallback bundle. The
+        device rungs run the cycle's plan: ``sparse`` as tensorize
+        built it, ``dense`` its dense form."""
         from ..solver import containment
 
         if rung == "native":
             return AsyncSolveHandle.launch(
-                ctx.host_inputs, True, self.max_rounds
+                ctx.host_inputs, None, self.max_rounds
             )
+        plan = ctx.plan if rung == "sparse" else ctx.plan.dense(
+            "ladder-degraded"
+        )
         return AsyncSolveHandle.launch(
-            inputs, False, self.max_rounds,
+            inputs, plan, self.max_rounds,
             fault_hook=containment.device_fault_hook(),
         )
 
@@ -734,19 +741,11 @@ class AllocateTpuAction(Action):
                 "device", "native", "breaker-open"
             )
 
-        # Degradation-ladder rungs for this cycle, top first. The top
-        # rung is whatever the backend decision + tensorize produced
-        # (candidate slabs → sparse program); every device cycle keeps
-        # dense and the native CPU floor below it, so a runtime device
-        # fault degrades scheduling quality, never the cycle.
-        if use_native:
-            rungs = ["native"]
-        else:
-            cand = getattr(inputs, "cand_idx", None)
-            sparse_slabs = cand is not None and int(cand.shape[0]) > 0
-            rungs = (["sparse"] if sparse_slabs else []) + [
-                "dense", "native"
-            ]
+        # Degradation-ladder rungs for this cycle, top first: the
+        # plan's device rungs (sparse when tensorize built slabs, then
+        # dense) above the native CPU floor, so a runtime device fault
+        # degrades scheduling quality, never the cycle.
+        rungs = ["native"] if use_native else ctx.plan.rungs() + ["native"]
 
         t0 = time.perf_counter()
         # OVERLAPPED solve: launch is async (device rounds via XLA
@@ -860,12 +859,8 @@ class AllocateTpuAction(Action):
             elif tsparse.get("enabled"):
                 # tensorize built slabs but the final solve ran dense:
                 # a ladder descent stripped them (the sparse rung
-                # failed), or a legacy explicit-staged call ignored
-                # them.
-                fallback_reason = (
-                    "ladder-degraded" if len(ladder) > 1
-                    else "sharded-mesh"
-                )
+                # failed).
+                fallback_reason = "ladder-degraded"
         if not engaged and fallback_reason is None:
             fallback_reason = tsparse.get("reason")
         last_stats["sparse_engaged"] = engaged

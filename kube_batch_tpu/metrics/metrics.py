@@ -397,7 +397,7 @@ solver_sparse_dense_fallbacks = REGISTRY.register(
     Counter(
         "solver_sparse_dense_fallbacks_total",
         "Solves that fell back to the dense path by reason "
-        "(class-budget/sharded-mesh/env-disabled/ladder-degraded)",
+        "(class-budget/env-disabled/ladder-degraded)",
     ),
     ("reason",),
 )
@@ -876,7 +876,7 @@ def update_device_cache(stats: dict) -> None:
 # path was wanted but could not run), as opposed to the size policy
 # simply preferring dense on a small problem.
 _SPARSE_FALLBACK_REASONS = frozenset(
-    ("class-budget", "sharded-mesh", "env-disabled", "ladder-degraded")
+    ("class-budget", "env-disabled", "ladder-degraded")
 )
 
 
@@ -894,7 +894,7 @@ def update_solver_sparse(
 
 def register_sparse_sharded(mode: str) -> None:
     """One cycle's sparse solve ran sharded over the mesh (mode =
-    flat | two-level, solver/sharding.sparse_shard_mode)."""
+    flat | two-level, solver/plan.py)."""
     solver_sparse_sharded.inc((mode or "unknown",))
 
 

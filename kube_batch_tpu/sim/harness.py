@@ -52,6 +52,11 @@ logger = logging.getLogger(__name__)
 
 SIM_NAMESPACE = "sim"
 
+# Wall-clock solve budget of a cycle with a planned solver-hang: the
+# hang outsleeps it, and it leaves a loaded host room for the native
+# floor's re-solve that the same budget bounds.
+_HANG_BUDGET_S = 2.0
+
 SIM_DEFAULT_CONF = """
 actions: "allocate_tpu, backfill"
 tiers:
@@ -329,10 +334,7 @@ class ClusterSimulator:
         # have been launched under.
         QUALITY.reset()
         self._quality_enabled = QUALITY.enabled
-        # Failover drill state: device-kind memo (successor instances
-        # must re-stamp the 0.5 s solve budget their Scheduler
-        # construction resets) and the kill switchboard.
-        self._device_kinds = device_kinds
+        # Failover drill state: the kill switchboard.
         for cut in sorted(set(cfg.kill_plan.values())):
             if cut not in CUT_POINTS:
                 raise ValueError(
@@ -360,16 +362,9 @@ class ClusterSimulator:
             # The active scheduler instance (endpoint/cache/binder/
             # scheduler); failover discards it and builds a successor.
             self._build_instance()
-            # Small REAL-time solve budget, stamped AFTER the Scheduler
-            # (whose constructor stamps the period-derived one): an
-            # injected hang costs a fraction of a second of wall time,
-            # not the production 30 s. Only when device faults are
-            # actually planned — the deadline measures WALL time, and a
-            # fault-free (or native) soak on a contended box must not
-            # turn a >0.5 s scheduling stall of a healthy solve into a
-            # SolveTimeout cycle error. The hook is the chaos seam the
-            # solver-exc/solver-hang/backend-loss kinds fire through.
-            # (_build_instance re-stamps it for successors too.)
+            # The hook is the chaos seam the solver-exc/solver-hang/
+            # backend-loss kinds fire through (each cycle stamps its
+            # solve budget: see _HANG_BUDGET_S).
             _containment.set_device_fault_hook(
                 self.injector.device_fault_hook()
             )
@@ -381,7 +376,7 @@ class ClusterSimulator:
             )
             if cfg.backend in ("dense", "sparse"):
                 # Pre-warm the breaker's canary jit so an in-run probe
-                # costs milliseconds against the 0.5 s budget — probe
+                # costs milliseconds against the solve budget — probe
                 # success must never hinge on a cold compile racing the
                 # deadline (that would make replays timing-dependent).
                 try:
@@ -606,10 +601,6 @@ class ClusterSimulator:
             schedule_period=cfg.period,
             clock=self.clock,
         )
-        if self._device_kinds:
-            # Scheduler construction re-stamped the period-derived
-            # budget; restore the drill's small wall-clock one.
-            self._containment.configure(solve_budget=0.5)
         if self._failover_enabled:
             # Virtual-time lease: the drill's takeover waits out the
             # real TTL on the virtual clock (renewed per cycle).
@@ -799,6 +790,16 @@ class ClusterSimulator:
             )
         if kill_cut is not None:
             self.endpoint.arm_kill(kill_cut, cycle)
+        # The solve deadline runs on the WALL clock while the sim runs
+        # on a virtual one: only a cycle with a planned hang gets the
+        # small budget its hang must outsleep, every other cycle the
+        # scheduler's period-derived one, so host load never turns a
+        # healthy solve or canary probe into a SolveTimeout or a
+        # breaker trip, and record and replay walk the same ladder.
+        if device_fault == "hang":
+            self._containment.configure(solve_budget=_HANG_BUDGET_S)
+        else:
+            self._containment.configure_from_period(cfg.period)
         self.injector.begin_cycle(
             cycle, doomed_nodes=doomed, solver_fault=device_fault,
             corrupt=corrupt_fault,

@@ -3,23 +3,17 @@
 The genuinely new component of the rebuild (SURVEY.md §7 step 6): the
 reference's per-task greedy allocate loop re-expressed as dense tensor ops —
 feasibility mask, cost matrix, round-based conflict-resolved assignment —
-jitted for TPU, with a sharded multi-chip variant.
+jitted for TPU, with a sharded multi-chip variant. Which program a cycle
+runs is decided once per cycle by the solve plan (plan.py).
 """
 
-from .device_cache import DeviceSnapshotCache, device_cache_of
 from .kernels import (
     PackedInputs,
-    SolverInputs,
-    SolverResult,
-    build_feasibility,
-    build_static_score,
-    dynamic_scores,
     jit_compilation_count,
     less_equal,
     make_inputs,
     segmented_cumsum,
     solve,
-    solve_auto,
     solve_full_jit,
     solve_jit,
     solve_sparse,
@@ -27,67 +21,42 @@ from .kernels import (
     solve_staged,
     solve_staged_jit,
 )
-from .masks import BatchMask, CombinedMask, combine_masks, combine_score_rows
-from .topk import TopKConfig, select_candidates, topk_config
+from .topk import select_candidates
 from .sharding import (
     default_mesh,
     init_distributed,
     pad_nodes,
     pad_tasks,
     sharded_step,
-    shardings_for,
     solve_sharded,
-    sparse_shard_mode,
 )
-from .snapshot import ResourceLayout, SnapshotContext, tensorize
-from .spmd import (
-    solve_sparse_spmd,
-    solve_spmd,
-    sparse_spmd_shardings_for,
-    spmd_shardings_for,
-)
+from .plan import SolvePlan, plan_for, solve_plan
+from .snapshot import tensorize
+from .spmd import solve_sparse_spmd
 
 __all__ = [
     "PackedInputs",
-    "SolverInputs",
-    "SolverResult",
-    "BatchMask",
-    "CombinedMask",
-    "DeviceSnapshotCache",
-    "ResourceLayout",
-    "SnapshotContext",
-    "device_cache_of",
-    "jit_compilation_count",
-    "build_feasibility",
-    "build_static_score",
-    "combine_masks",
-    "combine_score_rows",
+    "SolvePlan",
     "default_mesh",
     "init_distributed",
-    "dynamic_scores",
+    "jit_compilation_count",
     "less_equal",
     "make_inputs",
     "pad_nodes",
     "pad_tasks",
+    "plan_for",
     "segmented_cumsum",
+    "select_candidates",
     "sharded_step",
-    "shardings_for",
-    "sparse_shard_mode",
-    "sparse_spmd_shardings_for",
-    "solve_sparse_spmd",
     "solve",
-    "solve_auto",
     "solve_full_jit",
     "solve_jit",
+    "solve_plan",
     "solve_sharded",
     "solve_sparse",
     "solve_sparse_jit",
-    "solve_spmd",
-    "spmd_shardings_for",
+    "solve_sparse_spmd",
     "solve_staged",
     "solve_staged_jit",
-    "select_candidates",
     "tensorize",
-    "topk_config",
-    "TopKConfig",
 ]

@@ -150,7 +150,7 @@ class DeviceSnapshotCache:
         # Stats of a pack_partial() awaiting merge into the next pack()
         # (the two are one logical pack per cycle).
         self._deferred: Optional[dict] = None
-        # Solver device-layout key (sharding.packed_sparse_placement):
+        # Solver device-layout key (the solve plan's layout_token):
         # resident buffers are only reusable under the layout they were
         # placed for — a mesh/mode flip voids them all (labeled
         # ``mesh-change`` full re-upload).
@@ -306,8 +306,8 @@ class DeviceSnapshotCache:
         aggregate counters through ``metrics``.
 
         ``placement``/``layout_token`` parameterize residency by the
-        solver's device layout (sharding.packed_sparse_placement): a
-        token change drops every resident buffer — a buffer laid out
+        solver's device layout (the solve plan's fields): a token
+        change drops every resident buffer — a buffer laid out
         for one mesh/mode cannot be patched into another — and the
         whole snapshot re-uploads under the new placement, labeled
         ``mesh-change``."""

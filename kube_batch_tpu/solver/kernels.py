@@ -1267,20 +1267,24 @@ _STAGED_MIN_NODES = 768
 _STAGED_MIN_TASKS = 16384
 
 
+def staged_rule(n_tasks: int, n_nodes: int) -> bool:
+    """The staged-or-full rule for dense solves, on the bundle's padded
+    axes: the single-device trace and the solve plan both apply it."""
+    return n_nodes >= _STAGED_MIN_NODES and n_tasks >= _STAGED_MIN_TASKS
+
+
 def _dense_auto(shaped, max_rounds: int) -> SolverResult:
     """Shape dispatch between the full and staged DENSE solvers."""
-    T = shaped.task_req.shape[0]
-    N = shaped.node_idle.shape[0]
-    if N >= _STAGED_MIN_NODES and T >= _STAGED_MIN_TASKS:
+    if staged_rule(shaped.task_req.shape[0], shaped.node_idle.shape[0]):
         return solve_staged(shaped, max_rounds=max_rounds)
     return solve(shaped, max_rounds=max_rounds)
 
 
 def solve_auto(inputs, max_rounds: int = 256) -> SolverResult:
     """Dispatch by (static) snapshot shape: candidate-sparsified solve
-    when the snapshot carries candidate slabs (tensorize builds them per
-    solver/topk.topk_config — problem size policy + the KBT_SOLVER_TOPK
-    override), else the full/staged dense solver."""
+    when the snapshot carries candidate slabs (tensorize builds them
+    when the solve plan says sparse — solver/plan.py), else the
+    full/staged dense solver."""
     shaped = inputs.unpack() if isinstance(inputs, PackedInputs) else inputs
     if _cand_classes(shaped) > 0:
         return solve_sparse(shaped, max_rounds=max_rounds)
@@ -1310,13 +1314,13 @@ def jit_compilation_count() -> int:
     cycles means a shape/dtype drift reintroduced per-cycle tracing
     (pinned by tests/solver/test_retrace_guard.py; exported via
     metrics.solver_jit_compilations)."""
-    from . import sharding, spmd
+    from . import spmd
     from .device_cache import patch_jit_cache_size
     from .select_device import jit_cache_size as select_jit_cache_size
 
     total = 0
     fns = [solve_jit, solve_full_jit, solve_staged_jit, solve_sparse_jit]
-    for ref in spmd._jitted_steps + sharding._jitted_steps:
+    for ref in spmd._jitted_steps:
         fn = ref()
         if fn is not None:  # dead weakref = lru-evicted step
             fns.append(fn)

@@ -485,6 +485,7 @@ def select_rows(
     k: int,
     N: int,
     node_fp: Optional[tuple] = None,
+    layout_token: Optional[str] = None,
 ) -> Optional[dict]:
     """Run the device-resident selection for one cycle.
 
@@ -498,7 +499,6 @@ def select_rows(
     except Exception:  # pragma: no cover - jax baked into the image
         return None
     from .topk import _skey_priv_row
-    from .sharding import prospective_layout_token
 
     C = len(rep_idx)
     Np = state.n_padded
@@ -511,7 +511,7 @@ def select_rows(
     sig = (
         N, Np, int(k), rep_req.shape[1], eps32.tobytes(),
         float(lr_weight), float(br_weight),
-        state.layout_token or prospective_layout_token(),
+        state.layout_token or layout_token,
     )
     if (
         eng.sig != sig

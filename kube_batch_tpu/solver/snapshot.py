@@ -27,12 +27,15 @@ import functools
 import logging
 import os
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import TYPE_CHECKING, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from ..utils.lockdebug import wrap_lock
 from .contracts import contracts_enabled, validate_solver_inputs
+
+if TYPE_CHECKING:
+    from .plan import SolvePlan
 
 from ..api import (
     JobInfo,
@@ -135,6 +138,8 @@ class SnapshotContext:
     # task_rank values index into.
     subset_jobs: Optional[frozenset] = None
     rank_total: int = 0
+    # The cycle's solve plan: what the action dispatches.
+    plan: Optional["SolvePlan"] = None
 
 
 def _sorted_by(items, less_fn):
@@ -1067,19 +1072,24 @@ def tensorize(
     # initial-idle score pass. Runs against the UNPADDED node arrays
     # (host fallback) or the padded resident stacks (device path); the
     # slabs are padded/bucketed below with everything else.
-    from .topk import select_candidates, topk_config
+    from .plan import solve_plan
+    from .sharding import default_mesh
+    from .topk import select_candidates
 
-    tk = topk_config(T, N)
+    # The cycle's solve plan (solver/plan.py): sparse or dense, K, the
+    # mesh mode, the placement and the layout tokens, decided once here
+    # and carried on the context to the action. The native route never
+    # probes the backend for a mesh.
+    plan = solve_plan(T, N, default_mesh() if device else None,
+                      padded=(Tp, Np))
     cand_sel = None
-    sparse_reason = tk.reason
     device_state = None
-    if device and tk.enabled:
+    if device and plan.sparse:
         from .device_cache import device_cache_of
         from .select_device import (
             SelectionDeviceState,
             device_select_enabled,
         )
-        from .sharding import packed_sparse_placement
 
         dc0 = device_cache_of(ssn.cache)
         if (
@@ -1088,29 +1098,29 @@ def tensorize(
             and not bool(node_rel64.any())
         ):
             try:
-                placement0, token0 = packed_sparse_placement(Tp)
                 placed = dc0.pack_partial(
                     {
                         "node_f32": node_f32_stack,
                         "node_i32": node_i32_stack,
                         "group_feas": group_feas,
                     },
-                    placement=placement0, layout_token=token0,
+                    placement=plan.placement,
+                    layout_token=plan.layout_token,
                 )
                 device_state = SelectionDeviceState(
                     ssn.cache, placed["node_f32"], placed["node_i32"],
-                    placed["group_feas"], Np, token0,
+                    placed["group_feas"], Np, plan.layout_token,
                 )
             except Exception:  # pragma: no cover - fall back to host
                 logger.exception("device-selection pre-pack failed")
                 device_state = None
-    if tk.enabled:
-        with _span("topk_select", k=tk.k):
+    if plan.sparse:
+        with _span("topk_select", k=plan.k):
             cand_sel = select_candidates(
                 mask, score_rows_map, task_req, task_fit,
                 node_idle, node_cap, node_releasing,
                 node_task_count, node_max_tasks,
-                layout.eps(), lr_w, br_w, tk.k,
+                layout.eps(), lr_w, br_w, plan.k,
                 cache_holder=ssn.cache,
                 node_fp=(
                     (scan.ids, scan.vers, scan.nodes)
@@ -1118,13 +1128,14 @@ def tensorize(
                     else None
                 ),
                 device_state=device_state,
+                layout_token=plan.sel_token,
             )
         if cand_sel is None:
-            sparse_reason = "class-budget"
+            plan = plan.dense("class-budget")
     sparse_stats = {
-        "enabled": cand_sel is not None,
-        "k": tk.k,
-        "reason": sparse_reason,
+        "enabled": plan.sparse,
+        "k": plan.k,
+        "reason": plan.reason,
     }
     if cand_sel is not None:
         sparse_stats.update(cand_sel.stats)
@@ -1256,6 +1267,7 @@ def tensorize(
             frozenset(j.uid for j in include_jobs) if subset_mode else None
         ),
         rank_total=rank_total,
+        plan=plan,
     )
     if not device:
         return host_inputs, ctx
@@ -1292,19 +1304,12 @@ def tensorize(
         "cand_info": cand_info,
     }
     from .device_cache import device_cache_of
-    from .sharding import packed_sparse_placement
 
-    # Device placement for the sharded sparse path: when the shape/mesh
-    # policy will shard this snapshot's solve, resident buffers upload
-    # replicated on the mesh ONCE so the shard_map step never re-lays
-    # them out per cycle; the token keys residency to the layout.
-    placement, layout_token = packed_sparse_placement(
-        Tp if cand_sel is not None else 0
-    )
     dc = device_cache_of(ssn.cache)
     if dc is not None:
         return dc.pack(
-            stacked, placement=placement, layout_token=layout_token
+            stacked, placement=plan.placement,
+            layout_token=plan.layout_token,
         ), ctx
     import jax.numpy as jnp
 

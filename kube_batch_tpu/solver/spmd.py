@@ -1,13 +1,13 @@
 """Explicit-SPMD sharded solve: hierarchical conflict resolution.
 
-The GSPMD path (sharding.py) annotates the single-device program and lets
-XLA partition it. That is correct but collective-dominated at scale: the
-per-commit global argmax over a node-sharded [T, N] key matrix and the
-scatter that voids lost columns make GSPMD materialize cross-shard
-gathers of [T, N]-sized intermediates — measured 1.6x SLOWER than
-single-device at 10k x 1001 on the 8-device CPU mesh (MULTICHIP_r04).
+Letting GSPMD partition the single-device program is correct but
+collective-dominated at scale: the per-commit global argmax over a
+node-sharded [T, N] key matrix and the scatter that voids lost columns
+make it materialize cross-shard gathers of [T, N]-sized intermediates —
+measured 1.6x SLOWER than single-device at 10k x 1001 on the 8-device
+CPU mesh (MULTICHIP_r04), and since removed.
 
-This module instead writes the SPMD program explicitly with `shard_map`,
+This module writes the SPMD program explicitly with `shard_map`,
 restructuring conflict resolution hierarchically (VERDICT r4 item 2):
 
 - LOCAL bid: each shard owns N/s node columns. The O(T*N) work — fit
@@ -37,7 +37,7 @@ recipe: shard the big axis, gather only reductions); on the 1-core
 virtual CPU mesh the shards serialize, so the honest target there is
 parity with single-device, not speedup — the win is that the sharded
 program does no more TOTAL work than the single-device one, which the
-GSPMD version could not achieve.
+GSPMD partitioning could not achieve.
 
 Reference analog being replaced: the 16-worker PredicateNodes fan-out,
 util/scheduler_helper.go:84,137 — itself a shard-the-node-axis design.
@@ -106,8 +106,7 @@ def spmd_shardings_for(inputs, mesh: Mesh):
     def spec(f, sh):
         # None-able candidate-slab fields mirror as None so device_put
         # treedefs match (slabs replicate: they carry node IDS, and the
-        # sharded solvers run the dense path regardless — see
-        # solve_sharded's sparse note).
+        # dense shard_map solve ignores them).
         return None if getattr(inputs, f, None) is None else sh
 
     if isinstance(inputs, PackedInputs):
@@ -761,8 +760,8 @@ def solve_spmd(
 # (documented in doc/design/sparse-candidate-solver.md); node/queue
 # invariants are preserved exactly because every accept still goes
 # through `_commit_bids`. Two-level is NOT bit-equal to the
-# single-device solve — the shape policy (sharding.sparse_shard_mode)
-# only selects it far past the parity-suite shapes.
+# single-device solve — the shape policy (plan._shard_mode) only
+# selects it far past the parity-suite shapes.
 # ---------------------------------------------------------------------------
 
 

@@ -29,14 +29,13 @@ dense solver's no-fit verdict. Truncated classes route exhausted tasks
 to the refill stage instead (kernels._dense_tail), never to a false
 job break.
 
-``KBT_SOLVER_TOPK`` overrides the policy: an integer forces that K at
-any problem size; ``0``/``off``/``dense`` disables sparsification.
+Whether a snapshot sparsifies, and its K, is the solve plan's call
+(solver/plan.py).
 """
 
 from __future__ import annotations
 
 import hashlib
-import os
 from dataclasses import dataclass
 from typing import Dict, Optional
 
@@ -50,17 +49,6 @@ from .kernels import (
     MEM_DIM,
     SCORE_QUANTUM,
 )
-
-# Sparsification pays off once the dense [T, N] structures dominate and
-# the slab is a real subset; below these the dense solvers win outright.
-# The task floor is a PRODUCT bound, not a task count: a 500-task
-# arrival batch against 5 000 nodes is 2.5 M dense score cells (~13 ms
-# native) where selection costs C·N for a handful of classes — exactly
-# the warm steady-cycle shape, so small-T/large-N problems sparsify too.
-_SPARSE_MIN_TASKS = 64
-_SPARSE_MIN_CELLS = 1 << 20
-_SPARSE_MIN_NODES = 1024
-DEFAULT_K = 64
 
 # Selection itself costs O(C * N); if class dedup degenerates (every
 # task a distinct shape) that approaches the dense pass it is meant to
@@ -78,49 +66,10 @@ _CLASS_BUDGET_FACTOR = 4
 _TIE_BITS = 31
 
 
-@dataclass(frozen=True)
-class TopKConfig:
-    """Resolved candidate-sparsification policy for one snapshot."""
-
-    k: int
-    enabled: bool
-    reason: str
-
-
 def _pow2(n: int) -> int:
     if n <= 0:
         return 1
     return 1 << (n - 1).bit_length()
-
-
-def topk_config(n_tasks: int, n_nodes: int) -> TopKConfig:
-    """Resolve K and the sparse on/off decision for a (T, N) snapshot.
-
-    K is power-of-two bucketed (like the task-axis shape buckets) so a
-    configured K never mints per-value jit variants."""
-    raw = os.environ.get("KBT_SOLVER_TOPK", "").strip().lower()
-    if raw in ("0", "off", "dense", "disable", "disabled", "false"):
-        return TopKConfig(0, False, "env-disabled")
-    k = DEFAULT_K
-    forced = False
-    if raw:
-        try:
-            k = max(1, int(raw))
-            forced = True
-        except ValueError:
-            pass
-    k = _pow2(k)
-    if forced:
-        return TopKConfig(k, True, "env-forced")
-    if (
-        n_tasks < _SPARSE_MIN_TASKS
-        or n_nodes < _SPARSE_MIN_NODES
-        or n_tasks * n_nodes < _SPARSE_MIN_CELLS
-    ):
-        return TopKConfig(k, False, "small-problem")
-    if 4 * k >= n_nodes:
-        return TopKConfig(k, False, "k-covers-nodes")
-    return TopKConfig(k, True, "size-policy")
 
 
 @dataclass
@@ -132,19 +81,6 @@ class CandidateSet:
     cand_static: np.ndarray  # f32[C, K] static score slab
     cand_info: np.ndarray    # i32[3, C] total / any_feas / fits_releasing
     stats: dict
-
-
-def _layout_sig_token():
-    """Solver layout token folded into the selection-cache signatures
-    (host AND device): a mesh/mode/rack-map change reshuffles which
-    node block each shard owns, so carried key rows must invalidate
-    with the same ``mesh-changed`` semantics as the warm plan."""
-    try:
-        from .sharding import prospective_layout_token
-
-        return prospective_layout_token()
-    except Exception:  # pragma: no cover - sharding import must not kill
-        return None
 
 
 def _sel_hash(c_ids: np.ndarray, n_ids: np.ndarray) -> np.ndarray:
@@ -322,6 +258,8 @@ def select_candidates(
     node_fp: Optional[tuple] = None,
     # select_device.SelectionDeviceState or None
     device_state: Optional["SelectionDeviceState"] = None,
+    # the solve plan's sel_token (cache signature)
+    layout_token: Optional[str] = None,
 ) -> Optional[CandidateSet]:
     """Run the fused feasibility + static-score selection pass.
 
@@ -424,6 +362,7 @@ def select_candidates(
                 device_state, mask, rep_idx, rep_req, rep_fit, rep_priv,
                 score_rows_map, idle32, cap32, eps32, cap_ok0,
                 lr_weight, br_weight, k, N, node_fp=node_fp,
+                layout_token=layout_token,
             )
             select_path = (
                 "device" if dev_res is not None
@@ -465,7 +404,7 @@ def select_candidates(
     sc = _sel_cache_of(cache_holder) if node_fp is not None else None
     changed_cols = None
     sig = (N, int(k), R, eps32.tobytes(),
-           float(lr_weight), float(br_weight), _layout_sig_token())
+           float(lr_weight), float(br_weight), layout_token)
     if sc is not None and not has_releasing:
         ids, vers, node_objs = node_fp
         if (

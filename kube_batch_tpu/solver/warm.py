@@ -133,8 +133,8 @@ class WarmSolveState:
         # solves — a pure function of solve history, so replay-stable.
         self.drain_cursor = 0
         # Solver device-layout token at save time
-        # (sharding.prospective_layout_token); None until a sharded
-        # dispatch has pinned the device count.
+        # (plan.selection_token); None until a dispatch has pinned the
+        # device count.
         self.mesh_token = None
         # job uid -> (job clone object, clone _ver at save, pending
         # remainder at save). Identity+ver pins "untouched"; a
@@ -167,12 +167,12 @@ def warm_enabled() -> bool:
 
 def _layout_token():
     """The solver device-layout token a solve dispatched now would run
-    under (None before any sharded dispatch — see
-    sharding.prospective_layout_token; never probes the backend, so
-    the native-route and pre-init paths stay hang-safe)."""
-    from . import sharding
+    under (plan.selection_token: None before any dispatch; never
+    probes the backend, so the native-route and pre-init paths stay
+    hang-safe)."""
+    from .plan import selection_token
 
-    return sharding.prospective_layout_token()
+    return selection_token()
 
 
 def _res_eq(a, b) -> bool:

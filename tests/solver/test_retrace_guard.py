@@ -37,13 +37,13 @@ GUARD_CYCLES = 6  # steady/delta cycles that must stay trace-free
 def one_cycle(cache, tiers, churn, solver=None):
     """One tensorize → solve → apply-some cycle; churn keeps every axis
     inside its shape bucket (fixed task count per step, fixed node
-    fan-out) so no re-jit is legitimate."""
-    solver = solver or solve_jit
+    fan-out) so no re-jit is legitimate. ``solver(inputs, ctx)``, when
+    given, replaces the single-device jit."""
     ssn = open_session(cache, tiers)
     inputs, ctx = tensorize(ssn)
     placed = 0
     if inputs is not None:
-        result = solver(inputs)
+        result = solver(inputs, ctx) if solver else solve_jit(inputs)
         assigned = np.asarray(result.assigned)
         # Apply a FIXED-SIZE slice of the assignment through the
         # session so the mirror churns by the same amount every cycle.
@@ -145,7 +145,8 @@ def test_zero_new_compilations_sharded_sparse_cycles(monkeypatch):
     the 8-device mesh) must compile a bounded step set during warmup
     and then go flat — the sharded step AND the replicated-placement
     patch jits are all in the `jit_compilation_count` census
-    (spmd._jitted_steps weakrefs + patch_jit_cache_size)."""
+    (spmd._jitted_steps weakrefs + patch_jit_cache_size). Each cycle
+    dispatches the plan tensorize built, as the allocate action does."""
     import jax
 
     if len(jax.devices()) < 2:
@@ -154,15 +155,18 @@ def test_zero_new_compilations_sharded_sparse_cycles(monkeypatch):
     monkeypatch.setenv("KBT_SPARSE_SHARD_MODE", "flat")
     from kube_batch_tpu.solver import sharding as sharding_mod
 
+    def planned(inputs, ctx):
+        return solve_sharded(inputs, ctx.plan)
+
     c = build_cluster(seed=47, groups=6, per_group=40, nodes=8)
     tiers = make_tiers(*DEFAULT_TIERS_ARGS)
     for _ in range(WARM_CYCLES):
-        one_cycle(c, tiers, churn=2, solver=solve_sharded)
+        one_cycle(c, tiers, churn=2, solver=planned)
     assert sharding_mod.last_dispatch.get("mode") == "flat"
     warm = jit_compilation_count()
     assert warm > 0
     for cycle in range(GUARD_CYCLES):
-        one_cycle(c, tiers, churn=2, solver=solve_sharded)
+        one_cycle(c, tiers, churn=2, solver=planned)
         now = jit_compilation_count()
         assert now == warm, (
             f"sharded sparse cycle {cycle} minted {now - warm} new jit "

@@ -219,6 +219,7 @@ class TestLayoutTokenInvalidation:
     def _warm_then_flip(self, monkeypatch, device):
         from kube_batch_tpu.solver import sharding, select_device
         from kube_batch_tpu.solver.masks import CombinedMask
+        from kube_batch_tpu.solver.plan import selection_token
         from kube_batch_tpu.solver.topk import select_candidates
 
         monkeypatch.setitem(sharding._layout_state, "devices", 8)
@@ -263,6 +264,7 @@ class TestLayoutTokenInvalidation:
                 np.asarray([10.0, 10.0], np.float32), 1.0, 1.0, 8,
                 cache_holder=holder, node_fp=(ids, vers, None),
                 device_state=state,
+                layout_token=selection_token(),
             )
 
         run()

@@ -8,6 +8,7 @@ shapes, the staged solver, ragged node counts (padding), and the
 PackedInputs transfer format produced by ``tensorize``.
 """
 
+import dataclasses
 import os
 
 import numpy as np
@@ -23,6 +24,7 @@ from kube_batch_tpu.solver import (
     default_mesh,
     make_inputs,
     pad_nodes,
+    plan_for,
     solve,
     solve_sharded,
     solve_staged,
@@ -53,6 +55,12 @@ def synthetic_inputs(T, N, R=3, Q=2, J=None, seed=0, feas_p=0.9):
         lr_weight=jnp.asarray(1.0, jnp.float32),
         br_weight=jnp.asarray(1.0, jnp.float32),
     )
+
+
+def plan(inputs, mesh, **forced):
+    """The solve plan for ``inputs`` over ``mesh``, with ``forced``
+    fields (``staged``, ``tail_bucket``) set explicitly."""
+    return dataclasses.replace(plan_for(inputs, mesh), **forced)
 
 
 @pytest.fixture(scope="module")
@@ -88,7 +96,9 @@ class TestShardedParity:
         T, N = shape
         inputs = synthetic_inputs(T, N, seed=T + N)
         single = solve(inputs, max_rounds=64)
-        sharded = solve_sharded(inputs, mesh, max_rounds=64, staged=False)
+        sharded = solve_sharded(
+            inputs, plan(inputs, mesh, staged=False), max_rounds=64
+        )
         assert_same_result(single, sharded, N)
         assert int(np.asarray(sharded.assigned).max()) >= 0  # placed some
 
@@ -97,7 +107,9 @@ class TestShardedParity:
         # solve_sharded; padded nodes must never receive assignments.
         inputs = synthetic_inputs(48, 20, seed=7)
         single = solve(inputs, max_rounds=64)
-        sharded = solve_sharded(inputs, mesh, max_rounds=64, staged=False)
+        sharded = solve_sharded(
+            inputs, plan(inputs, mesh, staged=False), max_rounds=64
+        )
         assert_same_result(single, sharded, 20)
 
     def test_large_ragged_node_count(self, mesh):
@@ -107,7 +119,9 @@ class TestShardedParity:
         # other parity cases use (VERDICT r3 weakness 6).
         inputs = synthetic_inputs(256, 1001, seed=13)
         single = solve(inputs, max_rounds=64)
-        sharded = solve_sharded(inputs, mesh, max_rounds=64, staged=False)
+        sharded = solve_sharded(
+            inputs, plan(inputs, mesh, staged=False), max_rounds=64
+        )
         assert_same_result(single, sharded, 1001)
         assert int((np.asarray(sharded.assigned) >= 0).sum()) > 0
 
@@ -116,7 +130,8 @@ class TestShardedParity:
         inputs = synthetic_inputs(128, 64, seed=3)
         full = solve(inputs, max_rounds=64)
         sharded = solve_sharded(
-            inputs, mesh, max_rounds=64, staged=True, tail_bucket=32
+            inputs, plan(inputs, mesh, staged=True, tail_bucket=32),
+            max_rounds=64,
         )
         a1 = np.asarray(full.assigned)
         a2 = np.asarray(sharded.assigned)
@@ -140,26 +155,12 @@ class TestShardedParity:
             inputs = synthetic_inputs(192, 72, seed=21)
             single = solve(inputs, max_rounds=64)
             sharded = solve_sharded(
-                inputs, mesh, max_rounds=64, staged=False
+                inputs, plan(inputs, mesh, staged=False), max_rounds=64
             )
             assert_same_result(single, sharded, 72)
         finally:
             spmd._POOL_MAX_T = old
             spmd._spmd_step.cache_clear()
-
-    def test_gspmd_legacy_impl_matches(self, mesh):
-        # The auto-partitioned implementation stays available for A/B;
-        # both impls must agree with the single-device solve.
-        inputs = synthetic_inputs(96, 40, seed=17)
-        single = solve(inputs, max_rounds=64)
-        spmd_r = solve_sharded(
-            inputs, mesh, max_rounds=64, staged=False, impl="spmd"
-        )
-        gspmd_r = solve_sharded(
-            inputs, mesh, max_rounds=64, staged=False, impl="gspmd"
-        )
-        assert_same_result(single, spmd_r, 40)
-        assert_same_result(single, gspmd_r, 40)
 
     def test_queue_budgets_and_job_break_sharded(self, mesh):
         # Budget-capped queues and the job-break verdict cross the
@@ -177,7 +178,9 @@ class TestShardedParity:
             ].set(False),
         )
         single = solve(inputs, max_rounds=64)
-        sharded = solve_sharded(inputs, mesh, max_rounds=64, staged=False)
+        sharded = solve_sharded(
+            inputs, plan(inputs, mesh, staged=False), max_rounds=64
+        )
         assert_same_result(single, sharded, N)
 
     def test_staged_true_smaller_than_tail_bucket(self, mesh):
@@ -186,7 +189,9 @@ class TestShardedParity:
         # instead of tracing lax.top_k with k > T.
         inputs = synthetic_inputs(48, 16, seed=5)
         single = solve(inputs, max_rounds=64)
-        sharded = solve_sharded(inputs, mesh, max_rounds=64, staged=True)
+        sharded = solve_sharded(
+            inputs, plan(inputs, mesh, staged=True), max_rounds=64
+        )
         assert_same_result(single, sharded, 16)
 
     def test_smaller_mesh_subset(self, mesh):
@@ -194,7 +199,9 @@ class TestShardedParity:
         sub = Mesh(np.asarray(jax.devices()[:2]), ("nodes",))
         inputs = synthetic_inputs(32, 16, seed=11)
         single = solve(inputs, max_rounds=64)
-        sharded = solve_sharded(inputs, sub, max_rounds=64, staged=False)
+        sharded = solve_sharded(
+            inputs, plan(inputs, sub, staged=False), max_rounds=64
+        )
         assert_same_result(single, sharded, 16)
 
 
@@ -247,7 +254,7 @@ class TestShardedSnapshotPath:
             assert inputs is not None
             single = solve(inputs, max_rounds=64)
             sharded = solve_sharded(
-                inputs, mesh, max_rounds=64, staged=False
+                inputs, plan(inputs, mesh, staged=False), max_rounds=64
             )
             np.testing.assert_array_equal(
                 np.asarray(single.assigned), np.asarray(sharded.assigned)
@@ -280,7 +287,9 @@ os.environ["JAX_PROCESS_ID"] = "0"
 # included), so request the virtual devices via env only, then join.
 from kube_batch_tpu.utils.backend import set_host_device_count
 set_host_device_count(4)
-from kube_batch_tpu.solver import default_mesh, init_distributed, solve_sharded
+from kube_batch_tpu.solver import (
+    default_mesh, init_distributed, plan_for, solve_sharded,
+)
 assert init_distributed()
 import jax, jax.numpy as jnp
 from kube_batch_tpu.solver import make_inputs
@@ -304,7 +313,7 @@ inputs = make_inputs(
     lr_weight=jnp.asarray(1.0),
     br_weight=jnp.asarray(1.0),
 )
-res = solve_sharded(inputs, mesh)
+res = solve_sharded(inputs, plan_for(inputs, mesh))
 import numpy as np
 assert (np.asarray(res.assigned) >= 0).all()
 print("DISTRIBUTED_OK")

@@ -28,9 +28,9 @@ from kube_batch_tpu.solver import (
     select_candidates,
     solve,
     solve_jit,
+    solve_plan,
     solve_sparse,
     tensorize,
-    topk_config,
 )
 from kube_batch_tpu.solver.masks import CombinedMask
 
@@ -128,17 +128,17 @@ def random_case(seed, T=60, N=16, cap=6000):
 class TestTopkConfig:
     def test_env_forced_and_disabled(self, monkeypatch):
         monkeypatch.setenv("KBT_SOLVER_TOPK", "12")
-        tk = topk_config(10, 10)
-        assert tk.enabled and tk.k == 16  # pow2-bucketed
+        plan = solve_plan(10, 10, None)
+        assert plan.sparse and plan.k == 16  # pow2-bucketed
         for off in ("0", "off", "dense"):
             monkeypatch.setenv("KBT_SOLVER_TOPK", off)
-            assert not topk_config(10**6, 10**5).enabled
+            assert not solve_plan(10**6, 10**5, None).sparse
 
     def test_size_policy(self, monkeypatch):
         monkeypatch.delenv("KBT_SOLVER_TOPK", raising=False)
-        assert not topk_config(100, 100).enabled       # small problem
-        assert not topk_config(20000, 200).enabled     # k covers nodes
-        assert topk_config(20000, 5000).enabled
+        assert not solve_plan(100, 100, None).sparse     # small problem
+        assert not solve_plan(20000, 200, None).sparse   # below node floor
+        assert solve_plan(20000, 5000, None).sparse
 
 
 class TestSelection:
@@ -623,6 +623,6 @@ def test_tensorize_emits_slabs_when_forced(monkeypatch):
 def test_env_disabled_stays_dense(monkeypatch):
     monkeypatch.setenv("KBT_SOLVER_TOPK", "off")
     task_req, node_idle = random_case(0, T=20, N=8)
-    assert not topk_config(20, 8).enabled
+    assert not solve_plan(20, 8, None).sparse
     # os.environ must not leak into other tests (monkeypatch handles it).
     assert os.environ["KBT_SOLVER_TOPK"] == "off"

@@ -1,5 +1,5 @@
 """Sharded sparse solver tests (solver/spmd.py sparse path + the
-sharding.py dispatch policy + device-cache/warm composition).
+solve plan's dispatch policy + device-cache/warm composition).
 
 Parity contract (doc/design/sparse-candidate-solver.md, sharded-solve
 section): the FLAT task-sharded shard_map solve is BIT-IDENTICAL to
@@ -29,11 +29,12 @@ from kube_batch_tpu.solver import (
     default_mesh,
     make_inputs,
     pad_tasks,
+    plan_for,
     select_candidates,
+    solve_plan,
     solve_sharded,
     solve_sparse,
     solve_sparse_spmd,
-    sparse_shard_mode,
 )
 from kube_batch_tpu.solver import sharding as sharding_mod
 from kube_batch_tpu.solver.masks import CombinedMask
@@ -148,11 +149,11 @@ class TestFlatParity:
 
     def test_one_device_mesh_degenerate(self):
         # A 1-device "mesh" must dispatch to the single-device sparse
-        # jit (sparse_shard_mode -> single) and stay bit-equal.
+        # jit (the plan's mode -> single) and stay bit-equal.
         sub = Mesh(np.asarray(jax.devices()[:1]), ("nodes",))
         inputs = sparse_inputs(200, 96, seed=0)
         single = solve_sparse(inputs, max_rounds=256)
-        via = solve_sharded(inputs, sub)
+        via = solve_sharded(inputs, plan_for(inputs, sub))
         np.testing.assert_array_equal(
             np.asarray(single.assigned), np.asarray(via.assigned)
         )
@@ -173,7 +174,7 @@ class TestDispatch:
                                                    monkeypatch):
         monkeypatch.setenv("KBT_SPARSE_SHARD_MODE", "flat")
         inputs = sparse_inputs(240, 64, seed=9, tight=True)
-        res = solve_sharded(inputs)
+        res = solve_sharded(inputs, plan_for(inputs, mesh))
         disp = dict(sharding_mod.last_dispatch)
         assert disp["mode"] == "flat"
         assert disp["sparse_sharded"] is True
@@ -188,7 +189,7 @@ class TestDispatch:
         monkeypatch.delenv("KBT_SPARSE_SHARD_MODE", raising=False)
         inputs = sparse_inputs(240, 64, seed=9)
         single = solve_sparse(inputs, max_rounds=256)
-        res = solve_sharded(inputs)
+        res = solve_sharded(inputs, plan_for(inputs, mesh))
         assert sharding_mod.last_dispatch.get("mode") == "single"
         np.testing.assert_array_equal(
             np.asarray(single.assigned), np.asarray(res.assigned)
@@ -196,19 +197,24 @@ class TestDispatch:
 
     def test_policy_table(self, monkeypatch):
         monkeypatch.delenv("KBT_SPARSE_SHARD_MODE", raising=False)
+        monkeypatch.setenv("KBT_SOLVER_TOPK", "8")  # every case sparse
         m8 = default_mesh()
-        assert sparse_shard_mode(1 << 20, None) == "single"
-        assert sparse_shard_mode(1 << 10, m8) == "single"
-        assert sparse_shard_mode(1 << 17, m8) == "flat"
-        assert sparse_shard_mode(1 << 20, m8) == "two-level"
+
+        def mode(n_tasks, mesh):
+            return solve_plan(n_tasks, 4096, mesh).mode
+
+        assert mode(1 << 20, None) == "single"
+        assert mode(1 << 10, m8) == "single"
+        assert mode(1 << 17, m8) == "flat"
+        assert mode(1 << 20, m8) == "two-level"
         monkeypatch.setenv("KBT_SPARSE_SHARD_MODE", "off")
-        assert sparse_shard_mode(1 << 20, m8) == "single"
+        assert mode(1 << 20, m8) == "single"
         monkeypatch.setenv("KBT_SPARSE_SHARD_MODE", "flat")
-        assert sparse_shard_mode(16, m8) == "flat"
+        assert mode(16, m8) == "flat"
         monkeypatch.setenv("KBT_SPARSE_SHARD_MODE", "two-level")
-        assert sparse_shard_mode(16, m8) == "two-level"
+        assert mode(16, m8) == "two-level"
         # No mesh wins over any forcing (nothing to shard over).
-        assert sparse_shard_mode(1 << 20, None) == "single"
+        assert mode(1 << 20, None) == "single"
 
 
 class TestTwoLevel:

@@ -253,15 +253,24 @@ class TestDegradationLadder:
     def test_timeout_jumps_to_native_and_opens_breaker(self, monkeypatch):
         monkeypatch.setenv("KBT_SOLVER", "jax")
         monkeypatch.delenv("KBT_SOLVER_TOPK", raising=False)
-        containment.configure(solve_budget=0.15)
+        # The budget bounds every rung, the native floor's too: it must
+        # leave a loaded host room for the floor's solve, while the
+        # device rung cannot finish inside it whatever its length.
+        containment.configure(solve_budget=2.0)
+        released = threading.Event()
 
         def hook(stage):
             if stage == "solve":
-                time.sleep(0.6)  # outsleep the budget
+                # A wedged device sync: blocks until the cycle is over,
+                # so the deadline always fires first.
+                released.wait(30.0)
 
         containment.set_device_fault_hook(hook)
         c = _build_pending_cluster()
-        run_action(c, "allocate_tpu")
+        try:
+            run_action(c, "allocate_tpu")
+        finally:
+            released.set()
         assert c.wait_for_side_effects()
         ladder = atpu.last_stats["solve_ladder"]
         assert [(e["rung"], e["outcome"]) for e in ladder] == [

@@ -16,6 +16,7 @@ serialize, so the sharded number measures pure sharding overhead — the
 """
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -41,15 +42,16 @@ def main():
     from jax.sharding import Mesh
 
     import __graft_entry__ as g
-    from kube_batch_tpu.solver import solve_staged_jit, solve_sharded
+    from kube_batch_tpu.solver import plan_for, solve_staged_jit, solve_sharded
 
     big = g._synthetic_inputs(T=50_000, N=5_120, R=3, Q=5, J=2000, seed=2)
     mesh = Mesh(np.asarray(jax.devices()[: args.devices]), ("nodes",))
+    plan = dataclasses.replace(plan_for(big, mesh), staged=True)
 
     # Warm both compiles, then interleave best-of-2 (noisy box).
     single = jax.block_until_ready(solve_staged_jit(big, max_rounds=64))
     sharded = jax.block_until_ready(
-        solve_sharded(big, mesh, max_rounds=64, staged=True)
+        solve_sharded(big, plan, max_rounds=64)
     )
     t_single, t_sharded = [], []
     for _ in range(2):
@@ -58,7 +60,7 @@ def main():
         t_single.append(time.perf_counter() - t0)
         t0 = time.perf_counter()
         sharded = jax.block_until_ready(
-            solve_sharded(big, mesh, max_rounds=64, staged=True)
+            solve_sharded(big, plan, max_rounds=64)
         )
         t_sharded.append(time.perf_counter() - t0)
 
