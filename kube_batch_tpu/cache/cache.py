@@ -1600,7 +1600,7 @@ class SchedulerCache(Cache, EventHandlersMixin):
 
     def _bind_side_effect(self, pod, hostname, task_snapshot,
                           journal_seq: Optional[int] = None,
-                          mark_sink=None) -> None:
+                          mark_sink=None, stamp_sink=None) -> None:
         """Async half of bind. The volume bind wait (up to the reference's
         30s, cache.go:260-268) runs HERE on the side-effect pool, not in
         the scheduling loop — one slow volume must not stall every other
@@ -1609,7 +1609,12 @@ class SchedulerCache(Cache, EventHandlersMixin):
 
         ``mark_sink``: chunked callers pass a dict collecting this
         task's journal outcome; the chunk flushes them in ONE journal
-        round trip (_journal_mark_many) instead of one per task."""
+        round trip (_journal_mark_many) instead of one per task.
+        ``stamp_sink``: chunked callers (who pass ``mark_sink`` too)
+        pass a list collecting this task's ``(uid, ledger clock)``
+        applied stamp; the chunk folds them into the latency ledger in
+        one call (note_applied_many). A pod with a serving deadline
+        still resolves here, per bind."""
         if self._refused_by_fence(
             f"bind side effect {pod.namespace}/{pod.name} -> {hostname}"
         ):
@@ -1626,16 +1631,23 @@ class SchedulerCache(Cache, EventHandlersMixin):
             # call, the cache's own ``ingest`` of the bind event included.
             with TRACER.stage("bind_call"):
                 self.binder.bind(pod, hostname)
-            with TRACER.stage("ledgers"):
-                if mark_sink is not None:
-                    mark_sink[task_snapshot.uid] = "applied"
-                else:
-                    self._journal_mark(
-                        journal_seq, task_snapshot.uid, "applied")
-                # Placement-latency ledger: the applied stamp rides the
-                # journal-mark seam — the bind LANDED, so this timestamp
-                # is the truthful end of the pod's arrival→bind latency.
-                LEDGER.note_applied(task_snapshot.uid)
+            # Placement-latency ledger: the applied stamp rides the
+            # journal-mark seam — the bind LANDED, so this timestamp is
+            # the truthful end of the pod's arrival→bind latency.
+            uid = task_snapshot.uid
+            if stamp_sink is not None and not LEDGER.has_deadline(uid):
+                # The chunk flushes the mark and folds the stamp in its
+                # own ``ledgers`` entry (_do_binds).
+                with TRACER.stage("ledger_stamp"):
+                    mark_sink[uid] = "applied"
+                    stamp_sink.append((uid, LEDGER.now()))
+            else:
+                with TRACER.stage("ledgers"):
+                    if mark_sink is not None:
+                        mark_sink[uid] = "applied"
+                    else:
+                        self._journal_mark(journal_seq, uid, "applied")
+                    LEDGER.note_applied(uid)
             if self.cluster is not None:
                 with TRACER.stage("event"):
                     self.cluster.record_event(
@@ -1911,15 +1923,20 @@ class SchedulerCache(Cache, EventHandlersMixin):
                 # Chunked drain: journal marks collected per chunk and
                 # flushed in one round trip (one Lease CAS on a real
                 # cluster) — the fenced case leaves no sink entry, so
-                # those intents stay open for the successor.
+                # those intents stay open for the successor. The applied
+                # stamps, each read as its bind landed, fold into the
+                # latency ledger once per chunk.
                 marks: Dict[str, str] = {}
+                stamps: List[tuple] = []
                 for pod, hostname, task_snapshot in chunk:
                     self._bind_side_effect(
                         pod, hostname, task_snapshot,
                         journal_seq=journal_seq, mark_sink=marks,
+                        stamp_sink=stamps,
                     )
                 with TRACER.stage("ledgers"):
                     self._journal_mark_many(journal_seq, marks)
+                    LEDGER.note_applied_many(stamps)
 
             chunks = [
                 binds[start:start + self._BIND_CHUNK]

@@ -175,10 +175,15 @@ class _StageStats:
         self.sum = 0.0
         self.sketch = QuantileSketch()
 
-    def add(self, v: float) -> None:
-        self.count += 1
-        self.sum += v
-        self.sketch.add(v)
+    def add_many(self, values: List[float]) -> None:
+        """Fold ``values`` in order: count, sum (float adds in that
+        order) and sketch end as if each value came alone."""
+        self.count += len(values)
+        total = self.sum
+        for v in values:
+            total += v
+        self.sum = total
+        self.sketch.add_many(values)
 
     def to_dict(self) -> dict:
         out = {
@@ -411,102 +416,143 @@ class PlacementLedger:
                     e.dispatch_ts = now
                     e.stage = "dispatched"
 
+    def has_deadline(self, uid: str) -> bool:
+        """Whether ``uid`` is a pending pod with a serving SLO deadline.
+        :meth:`serving_pressure` reads those deadlines, so the bind drain
+        resolves such a pod per bind, never a chunk late."""
+        with self._lock:
+            return uid in self._serving_pending
+
     def note_applied(self, uid: str) -> None:
         """The bind side effect APPLIED (the journal-mark seam): the
-        truthful end of this pod's placement latency. Emits the stage
-        samples, advances the gang accounting, and drops the entry."""
+        truthful end of this pod's placement latency, stamped now."""
+        self.note_applied_many(((uid, None),))
+
+    def note_applied_many(
+        self, stamps: Iterable[Tuple[str, Optional[float]]],
+    ) -> None:
+        """Applied binds as ``(uid, ts)`` in the order they applied, ``ts``
+        the ledger clock read when the bind landed (None: read it here).
+        Per entry, in order: emits the stage samples, advances the gang
+        accounting and the SLO counts, and drops the entry. The samples
+        fold per ``(queue, kind, stage)`` series under one lock hold; the
+        histogram takes one ``observe_many`` per label set and the SLO
+        gauges update once."""
         if not self.enabled:
             return
-        metric_samples: List[Tuple[str, str, str, float]] = []
-        slo_sample: Optional[Tuple[str, bool]] = None
+        # (queue, kind, stage) -> samples in order; keys in the order a
+        # per-entry walk would create them.
+        series: Dict[Tuple[str, str, str], List[float]] = {}
+        by_qk: Dict[Tuple[str, str], List[List[float]]] = {}
+        slo_samples: List[Tuple[str, bool]] = []
+        # Only the newest DONE_CAPACITY records survive in the ring.
+        done: deque = deque(maxlen=DONE_CAPACITY)
         with self._lock:
-            e = self._entries.pop(uid, None)
-            if e is None:
-                return
-            self._serving_pending.pop(uid, None)
-            now = self._clock()
-            placed = e.placed_ts if e.placed_ts is not None else (
-                e.dispatch_ts if e.dispatch_ts is not None else now
-            )
-            dispatch = e.dispatch_ts if e.dispatch_ts is not None else placed
-            solve = max(0.0, min(e.solve_s, placed - e.arrival_ts))
-            stages = {
-                "queue_wait": max(0.0, placed - e.arrival_ts - solve),
-                "solve": solve,
-                "dispatch": max(0.0, dispatch - placed),
-                "bind": max(0.0, now - dispatch),
-                "total": max(0.0, now - e.arrival_ts),
-            }
-            queue, kind = e.queue or "-", e.cycle_kind
-            for stage, v in stages.items():
-                self._stage_stats(queue, kind, stage).add(v)
-                metric_samples.append((stage, queue, kind, v))
-            self.applied += 1
-            # SLO verdict at the truthful bind-applied moment: a pod of
-            # a targeted job met its SLO iff total <= target.
-            target = self._slo_targets.get(e.job)
-            if target is not None:
-                cls = (
-                    "serving" if e.job in self._serving_jobs else "batch"
+            for uid, now in stamps:
+                e = self._entries.pop(uid, None)
+                if e is None:
+                    continue
+                self._serving_pending.pop(uid, None)
+                if now is None:
+                    now = self._clock()
+                placed = e.placed_ts if e.placed_ts is not None else (
+                    e.dispatch_ts if e.dispatch_ts is not None else now
                 )
-                met = stages["total"] <= target
-                counts = self._slo_counts.get(cls)
-                if counts is None:
-                    counts = self._slo_counts[cls] = [0, 0, 0]
-                counts[0] += 1
-                counts[1 if met else 2] += 1
-                self._job_slo_applied[e.job] = (
-                    self._job_slo_applied.get(e.job, 0) + 1
+                dispatch = (
+                    e.dispatch_ts if e.dispatch_ts is not None else placed
                 )
-                if not met:
-                    self._job_slo_missed[e.job] = (
-                        self._job_slo_missed.get(e.job, 0) + 1
+                solve = max(0.0, min(e.solve_s, placed - e.arrival_ts))
+                total = max(0.0, now - e.arrival_ts)
+                values = (  # STAGES order
+                    max(0.0, placed - e.arrival_ts - solve),
+                    solve,
+                    max(0.0, dispatch - placed),
+                    max(0.0, now - dispatch),
+                    total,
+                )
+                queue, kind = e.queue or "-", e.cycle_kind
+                lists = by_qk.get((queue, kind))
+                if lists is None:
+                    lists = by_qk[(queue, kind)] = [
+                        series.setdefault((queue, kind, stage), [])
+                        for stage in STAGES
+                    ]
+                for samples, v in zip(lists, values):
+                    samples.append(v)
+                self.applied += 1
+                # SLO verdict at the truthful bind-applied moment: a pod
+                # of a targeted job met its SLO iff total <= target.
+                target = self._slo_targets.get(e.job)
+                if target is not None:
+                    cls = (
+                        "serving" if e.job in self._serving_jobs
+                        else "batch"
                     )
-                slo_sample = (cls, met)
-            members = self._by_job.get(e.job)
-            if members is not None and uid in members:
-                members.remove(uid)
-            jw = self._jobs.get(e.job)
-            if jw is not None:
-                jw.applied += 1
-                if queue != "-":
-                    jw.queue = queue
-                # Gang semantics: the gang's latency is its LAST
-                # member's bind-applied. When no member of the current
-                # wave is left pending, close the wave; later arrivals
-                # (rebirths, scale-ups) open a new one.
-                if not members and jw.first_arrival_ts is not None:
-                    gang_total = max(0.0, now - jw.first_arrival_ts)
-                    if jw.applied > 1:
-                        self._stage_stats(
-                            jw.queue or queue, kind, GANG_STAGE
-                        ).add(gang_total)
-                        self.gang_samples += 1
-                        metric_samples.append((
-                            GANG_STAGE, jw.queue or queue, kind,
-                            gang_total,
-                        ))
-                    jw.first_arrival_ts = None
-                    jw.arrivals = 0
-                    jw.applied = 0
-                    jw.cycles_waited = 0
-            self._done.append({
-                "pod": e.pod, "job": e.job, "queue": queue,
-                "cycle_kind": kind, "requeues": e.requeues,
-                **{f"{k}_s": round(v, 6) for k, v in stages.items()},
-            })
+                    met = total <= target
+                    counts = self._slo_counts.get(cls)
+                    if counts is None:
+                        counts = self._slo_counts[cls] = [0, 0, 0]
+                    counts[0] += 1
+                    counts[1 if met else 2] += 1
+                    self._job_slo_applied[e.job] = (
+                        self._job_slo_applied.get(e.job, 0) + 1
+                    )
+                    if not met:
+                        self._job_slo_missed[e.job] = (
+                            self._job_slo_missed.get(e.job, 0) + 1
+                        )
+                    slo_samples.append((cls, met))
+                members = self._by_job.get(e.job)
+                if members is not None and uid in members:
+                    members.remove(uid)
+                jw = self._jobs.get(e.job)
+                if jw is not None:
+                    jw.applied += 1
+                    if queue != "-":
+                        jw.queue = queue
+                    # Gang semantics: the gang's latency is its LAST
+                    # member's bind-applied. When no member of the
+                    # current wave is left pending, close the wave;
+                    # later arrivals (rebirths, scale-ups) open a new
+                    # one.
+                    if not members and jw.first_arrival_ts is not None:
+                        if jw.applied > 1:
+                            series.setdefault(
+                                (jw.queue or queue, kind, GANG_STAGE), []
+                            ).append(
+                                max(0.0, now - jw.first_arrival_ts)
+                            )
+                            self.gang_samples += 1
+                        jw.first_arrival_ts = None
+                        jw.arrivals = 0
+                        jw.applied = 0
+                        jw.cycles_waited = 0
+                done.append((e, queue, kind, values))
+            for (queue, kind, stage), samples in series.items():
+                self._stage_stats(queue, kind, stage).add_many(samples)
+            self._done.extend(
+                {
+                    "pod": e.pod, "job": e.job, "queue": queue,
+                    "cycle_kind": kind, "requeues": e.requeues,
+                    **{f"{k}_s": round(v, 6)
+                       for k, v in zip(STAGES, values)},
+                }
+                for e, queue, kind, values in done
+            )
         # Prometheus outside the ledger lock (the registry has its own
         # locks; no cross-lock hold).
         try:
             from .. import metrics
 
-            for stage, q, kind, v in metric_samples:
-                metrics.observe_placement_latency(stage, q, kind, v)
-            if slo_sample is not None:
-                cls, met = slo_sample
+            for (queue, kind, stage), samples in series.items():
+                metrics.observe_placement_latencies(
+                    stage, queue, kind, samples
+                )
+            for cls, met in slo_samples:
                 metrics.pod_slo_placements.inc(
                     (cls, "met" if met else "missed")
                 )
+            if slo_samples:
                 serving = self.serving_summary()
                 metrics.serving_slo_attainment.set(
                     serving["attainment_pct"] / 100.0

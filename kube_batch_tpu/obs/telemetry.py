@@ -37,7 +37,7 @@ import os
 import time
 import weakref
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, Iterable, List, Optional
 
 from ..utils.lockdebug import witness_writes, wrap_lock
 
@@ -101,16 +101,29 @@ class QuantileSketch:
         self.low_min = 0.0
 
     def add(self, value: float) -> None:
-        self.count += 1
-        if value <= 0.0:
-            if self.low_count == 0 or value < self.low_min:
-                self.low_min = value
-            self.low_count += 1
-            return
-        idx = math.ceil(math.log(value) / self._log_gamma)
-        self.buckets[idx] = self.buckets.get(idx, 0) + 1
-        if len(self.buckets) > self.max_buckets:
-            self._collapse_lowest()
+        self.add_many((value,))
+
+    def add_many(self, values: Iterable[float]) -> None:
+        """Add each value in order: a positive value to its log bucket,
+        any other to the exact min; past ``max_buckets`` (only a value
+        that opens a bucket can pass it) the lowest two merge."""
+        log, ceil, log_gamma = math.log, math.ceil, self._log_gamma
+        buckets = self.buckets
+        for value in values:
+            self.count += 1
+            if value <= 0.0:
+                if self.low_count == 0 or value < self.low_min:
+                    self.low_min = value
+                self.low_count += 1
+                continue
+            idx = ceil(log(value) / log_gamma)
+            n = buckets.get(idx)
+            if n is not None:
+                buckets[idx] = n + 1
+                continue
+            buckets[idx] = 1
+            if len(buckets) > self.max_buckets:
+                self._collapse_lowest()
 
     def _collapse_lowest(self) -> None:
         lo = sorted(self.buckets)[:2]

@@ -565,15 +565,17 @@ class TestBindPathStages:
             # the cache's ingest runs inside the cluster's bind call
             assert a["bind_call_n"] == a["event_n"] == a["ingest_n"]
             assert a["ingest_s"] <= a["bind_call_s"]
-            assert a["bind_call_s"] + a["ledgers_s"] + a["event_s"] \
-                <= e["dur"] / 1e6
+            assert a["bind_call_s"] + a["ledgers_s"] + a["ledger_stamp_s"] \
+                + a["event_s"] <= e["dur"] / 1e6
             assert a["ingest_cpu_s"] <= a["bind_call_cpu_s"]
             assert a["bind_call_cpu_s"] + a["ledgers_cpu_s"] \
                 + a["event_cpu_s"] <= a["cpu_s"]
             assert a["mutex_wait_n"] == a["ingest_n"]
             assert "mutex_wait_cpu_s" not in a  # a lock wait is wall only
-            # per bind, plus each chunk's journal-mark flush
-            assert a["ledgers_n"] == a["ingest_n"] + a.get("drain_chunk_n", 1)
+            # a stamp per bind; each chunk's journal-mark flush and
+            # ledger fold
+            assert a["ledger_stamp_n"] == a["ingest_n"]
+            assert a["ledgers_n"] == a.get("drain_chunk_n", 1)
         (book,) = [e for e in events if e["name"] == "cache_bookkeeping"]
         assert book["args"]["cpu_s"] > 0 and book["args"]["ledgers_n"] == 2
         # staging hold, then the prewarm holds (one job, one node)
@@ -721,6 +723,114 @@ class TestOrderedBindDrain:
         assert node_of == {"p0": "n1", "p1": "n1", "p2": "", "p3": "",
                            "p4": "n1", "p5": "n1"}
         assert cache._inflight == 0
+
+
+class TestLedgerFoldOnTheDrain:
+    """The drain stamps each applied bind with the ledger clock as it
+    lands and folds a chunk's stamps into the latency ledger at once; a
+    pod with a serving deadline still resolves per bind."""
+
+    @pytest.fixture
+    def ledger(self):
+        import time
+
+        from kube_batch_tpu.obs.latency import LEDGER
+
+        LEDGER.reset()
+        yield LEDGER
+        LEDGER.reset()
+        LEDGER.configure(clock=time.monotonic)
+
+    @staticmethod
+    def _spy_folds(ledger):
+        """Record the stamps of every note_applied_many call (a per-bind
+        note_applied arrives as one stamp with no reading)."""
+        calls = []
+        fold = ledger.note_applied_many
+
+        def spy(stamps):
+            calls.append(list(stamps))
+            fold(stamps)
+
+        ledger.note_applied_many = spy
+        return calls
+
+    def test_stamps_are_per_bind_and_fold_per_chunk(self, ledger):
+        ticks = iter(range(10**6))
+        ledger.configure(clock=lambda: float(next(ticks)))
+        cluster = InProcessCluster(simulate_kubelet=True)
+        _gang_of(cluster, 6)
+        cache = SchedulerCache(cluster=cluster)
+        cache._BIND_CHUNK = 2  # 3 chunks
+        cache.start_ingest()
+        assert ledger.stamped == 6
+        calls = self._spy_folds(ledger)
+        try:
+            cache.bind_batch(_bind_infos(cache, [f"p{i}" for i in range(6)]))
+            assert cache.wait_for_side_effects(timeout=10)
+        finally:
+            del ledger.note_applied_many
+            cache.shutdown()
+        assert ledger.entry_count() == 0 and ledger.applied == 6
+        assert [[uid for uid, _ in c] for c in calls] == [
+            ["ns-p0", "ns-p1"], ["ns-p2", "ns-p3"], ["ns-p4", "ns-p5"]]
+        stamps = [ts for c in calls for _, ts in c]
+        assert stamps == sorted(set(stamps))  # one reading per bind
+        binds = [r["bind_s"] for r in ledger.snapshot()["recent_applied"]]
+        assert len(binds) == 6 and len(set(binds)) == 6
+
+    def test_a_pod_with_a_serving_deadline_resolves_per_bind(self, ledger):
+        from kube_batch_tpu.api import (
+            SLO_SECONDS_ANNOTATION_KEY,
+            WORKLOAD_CLASS_ANNOTATION_KEY,
+        )
+
+        now = [0.0]
+        ledger.configure(clock=lambda: now[0])
+        cluster = InProcessCluster(simulate_kubelet=True)
+        _gang_of(cluster, 5)
+        cluster.create_pod_group(
+            build_pod_group("srv", namespace="ns", min_member=1))
+        pod = build_pod("ns", "s0", "", PodPhase.PENDING,
+                        build_resource_list(cpu="500m", memory="256Mi"),
+                        group_name="srv")
+        pod.metadata.annotations[WORKLOAD_CLASS_ANNOTATION_KEY] = "serving"
+        pod.metadata.annotations[SLO_SECONDS_ANNOTATION_KEY] = "1"
+        cluster.create_pod(pod)
+        cache = SchedulerCache(cluster=cluster)
+        cache._BIND_CHUNK = 4
+        cache.start_ingest()
+        now[0] = 100.0  # the serving pod's deadline has passed
+        assert ledger.serving_pressure()
+        with cache.mutex:
+            serving = cache.jobs["ns/srv"].tasks["ns-s0"].clone()
+        serving.node_name, serving.volume_ready = "n1", True
+        infos = _bind_infos(cache, ["p0", "p1"]) + [serving] + \
+            _bind_infos(cache, ["p2", "p3", "p4"])
+        pressure = []
+        bind_one = cache._bind_side_effect
+
+        def spy(*args, **kwargs):
+            bind_one(*args, **kwargs)
+            pressure.append(ledger.serving_pressure())
+
+        cache._bind_side_effect = spy
+        calls = self._spy_folds(ledger)
+        try:
+            cache.bind_batch(infos)
+            assert cache.wait_for_side_effects(timeout=10)
+        finally:
+            del ledger.note_applied_many
+            cache.shutdown()
+        # the pressure clears as the serving pod's bind applies, as one
+        # note_applied per bind clears it, not at its chunk's fold
+        assert pressure == [True, True, False, False, False, False]
+        assert [("ns-s0", None)] in calls
+        folded = [uid for c in calls for uid, ts in c if ts is not None]
+        assert folded == ["ns-p0", "ns-p1", "ns-p2", "ns-p3", "ns-p4"]
+        assert ledger.entry_count() == 0
+        assert ledger.serving_summary()["classes"]["serving"] == {
+            "placed": 1, "met": 0, "missed": 1, "attainment_pct": 0.0}
 
 
 class TestPooledBindDrain:

@@ -227,6 +227,143 @@ def test_disabled_ledger_is_inert():
     assert ledger.entry_count() == 0 and ledger.stamped == 0
 
 
+# -- applied stamps folded per chunk ------------------------------------------
+
+
+def _fold_scenario(ledger, clock):
+    """Drive a ledger to the eve of a bind drain and return its applied
+    stamps ``(uid, ts)`` in bind order: four queues, periodic and micro
+    placements, gangs of 60-90 pods, a gang whose wave closes mid-list
+    followed by other gangs' binds, a second wave of that gang, an
+    SLO-targeted job that meets its target on some pods and misses it on
+    others, a requeued pod, and a uid the ledger never saw."""
+    import random
+
+    rng = random.Random(29)
+    jobs = {}
+    for g in range(6):
+        job = f"t/g{g}"
+        jobs[job] = [f"{job}-{i}" for i in range(60 + 6 * g)]
+    jobs["t/short"] = [f"t/short-{i}" for i in range(3)]
+    for job, uids in jobs.items():
+        for uid in uids:
+            clock.tick(rng.uniform(0.0, 0.02))
+            ledger.note_arrival(uid, uid, job)
+    slo = [f"t/srv-{i}" for i in range(8)]
+    for uid in slo:
+        ledger.note_arrival(uid, uid, "t/srv", workload_class="serving",
+                            slo_target=7.0)
+    queues = {job: f"q{n % 4}" for n, job in enumerate(jobs)}
+    queues["t/srv"] = "q1"
+    clock.tick(1.0)
+    for n, (job, uids) in enumerate(jobs.items()):
+        ledger.note_placed(((u, job) for u in uids), queues,
+                           kind="micro" if n % 3 == 2 else "periodic",
+                           solve_s=rng.uniform(0.05, 0.5))
+        clock.tick(0.1)
+    ledger.note_placed(((u, "t/srv") for u in slo), queues, solve_s=0.2)
+    ledger.note_dispatched([u for uids in jobs.values() for u in uids])
+    ledger.note_dispatched(slo)
+    ledger.note_bind_failed("t/g0-1")  # requeued, then placed again
+    clock.tick(0.5)
+    ledger.note_placed((("t/g0-1", "t/g0"),), queues, solve_s=0.1)
+    ledger.note_dispatched(("t/g0-1",))
+    order = [u for job in ("t/g0", "t/g1") for u in jobs[job]]
+    order += jobs["t/short"]  # the wave closes here, mid-list
+    order += slo[:4] + ["t/never-seen"]
+    rest = [u for job in ("t/g2", "t/g3", "t/g4", "t/g5")
+            for u in jobs[job]]
+    rng.shuffle(rest)
+    order += rest[:200] + slo[4:] + rest[200:]
+    stamps = []
+    for n, uid in enumerate(order):
+        clock.tick(rng.uniform(0.0, 0.004) if n % 50 else 1.0)
+        stamps.append((uid, clock.t))
+        if uid == "t/short-2":  # a new wave of the short gang opens
+            ledger.note_arrival("t/short-3", "t/short-3", "t/short")
+    clock.tick(0.3)
+    ledger.note_placed((("t/short-3", "t/short"),), queues)
+    ledger.note_dispatched(("t/short-3",))
+    clock.tick(0.2)
+    stamps.append(("t/short-3", clock.t))
+    return stamps
+
+
+def _ledger_state(ledger, hist, slo_counter):
+    return {
+        "sketch_keys": list(ledger._sketches),
+        "sketches": {
+            key: (s.count, s.sum, s.sketch.count, dict(s.sketch.buckets),
+                  s.sketch.low_count, s.sketch.low_min)
+            for key, s in ledger._sketches.items()
+        },
+        "percentiles": ledger.percentiles(),
+        "stage_percentiles": ledger.stage_percentiles(),
+        "counters": (ledger.stamped, ledger.applied, ledger.gang_samples,
+                     ledger.bind_failures, ledger.requeues),
+        "slo": (ledger.serving_summary(), dict(ledger._slo_counts),
+                dict(ledger._job_slo_applied),
+                dict(ledger._job_slo_missed)),
+        "jobs": {
+            job: (jw.applied, jw.arrivals, jw.first_arrival_ts,
+                  jw.cycles_waited, jw.queue)
+            for job, jw in ledger._jobs.items()
+        },
+        "entries": sorted(ledger._entries),
+        "done": list(ledger._done),
+        "histogram": (dict(hist._counts), dict(hist._sums),
+                      dict(hist._totals)),
+        "slo_placements": dict(slo_counter._values),
+    }
+
+
+@pytest.mark.parametrize("chunk", [1, 7, 1024])
+def test_folded_stamps_equal_per_bind_applied(chunk, monkeypatch):
+    """note_applied_many over a drain's ``(uid, ts)`` stamps leaves the
+    ledger, its histogram and its SLO counts exactly as one note_applied
+    per uid at those clock readings does, chunk by chunk in any size."""
+    from kube_batch_tpu.metrics import metrics as metrics_mod
+
+    def run(fold):
+        hist = metrics.Histogram(
+            "t_placement", buckets=metrics_mod.LATENCY_BUCKETS)
+        slo_counter = metrics.Counter("t_slo")
+        monkeypatch.setattr(metrics_mod, "pod_placement_latency", hist)
+        monkeypatch.setattr(metrics, "pod_slo_placements", slo_counter)
+        ledger, clock = _ledger_with_clock()
+        stamps = _fold_scenario(ledger, clock)
+        if fold:
+            for start in range(0, len(stamps), chunk):
+                ledger.note_applied_many(stamps[start:start + chunk])
+        else:
+            for uid, ts in stamps:
+                clock.t = ts
+                ledger.note_applied(uid)
+        return _ledger_state(ledger, hist, slo_counter)
+
+    per_bind, folded = run(False), run(True)
+    assert folded == per_bind
+    # The scenario reaches what it is meant to.
+    assert per_bind["entries"] == []
+    assert per_bind["counters"][1] == 462  # every pod stamped applied
+    assert len(per_bind["done"]) == 256
+    assert per_bind["counters"][2] == 8  # gangs g0-g5, t/srv, t/short
+    placed, met, missed = per_bind["slo"][1]["serving"]
+    assert placed == 8 and met and missed
+    assert per_bind["slo_placements"]
+    assert {kind for q in per_bind["percentiles"].values()
+            for kind in q} == {"periodic", "micro"}
+
+
+def test_fold_of_unknown_or_no_stamps_changes_nothing():
+    ledger, clock = _ledger_with_clock()
+    ledger.note_arrival("u1", "t/p1", "t/job")
+    ledger.note_applied_many([])
+    ledger.note_applied_many([("u-unknown", 1.0)])
+    assert ledger.applied == 0 and ledger.entry_count() == 1
+    assert ledger.percentiles() == {}
+
+
 # -- cache/action integration ------------------------------------------------
 
 

@@ -7,6 +7,8 @@ import math
 import random
 import urllib.request
 
+import pytest
+
 from kube_batch_tpu import metrics
 from kube_batch_tpu.obs.telemetry import (
     TELEMETRY,
@@ -79,6 +81,65 @@ def test_sketch_bucket_collapse_bounded():
     values.sort()
     true99 = values[int(0.99 * (len(values) - 1))]
     assert abs(sketch.quantile(0.99) - true99) / true99 <= 0.0501
+
+
+
+def _one_at_a_time(sketch, value):
+    """The sketch's add, one value at a time, written out: log bucket,
+    exact non-positive min, lowest two buckets merged past the cap."""
+    sketch.count += 1
+    if value <= 0.0:
+        if sketch.low_count == 0 or value < sketch.low_min:
+            sketch.low_min = value
+        sketch.low_count += 1
+        return
+    idx = math.ceil(math.log(value) / sketch._log_gamma)
+    sketch.buckets[idx] = sketch.buckets.get(idx, 0) + 1
+    if len(sketch.buckets) > sketch.max_buckets:
+        low, next_low = sorted(sketch.buckets)[:2]
+        sketch.buckets[next_low] += sketch.buckets.pop(low)
+
+
+@pytest.mark.parametrize("max_buckets", [4, 32, 512])
+def test_sketch_add_many_equals_one_at_a_time(max_buckets):
+    """A batch leaves the buckets, count, low count and min exactly as
+    the same values added one at a time in order, collapses included."""
+    rng = random.Random(max_buckets)
+    values = [10 ** rng.uniform(-6, 6) for _ in range(2_000)]
+    for i in range(0, len(values), 97):
+        values[i] = (0.0, -1.5, -0.25)[i % 3]
+    batched = QuantileSketch(max_buckets=max_buckets)
+    for start in range(0, len(values), 300):
+        batched.add_many(values[start:start + 300])
+    single = QuantileSketch(max_buckets=max_buckets)
+    for v in values:
+        _one_at_a_time(single, v)
+    assert batched.buckets == single.buckets
+    assert (batched.count, batched.low_count, batched.low_min) == \
+        (single.count, single.low_count, single.low_min)
+    if max_buckets < 512:
+        assert len(batched.buckets) == max_buckets
+
+
+def test_histogram_observe_many_equals_observe():
+    """Batched observations: the same cumulative bucket counts, count
+    and sum (float adds in order) as one observe per value."""
+    from kube_batch_tpu.metrics.metrics import LATENCY_BUCKETS, Histogram
+
+    rng = random.Random(5)
+    values = [rng.expovariate(1 / 3.0) for _ in range(5_000)]
+    values += [0.0, 0.001, 10.0, 400.0]
+    one, many = (Histogram("t_one", buckets=LATENCY_BUCKETS),
+                 Histogram("t_many", buckets=LATENCY_BUCKETS))
+    for v in values:
+        one.observe(v, ("q",))
+    for start in range(0, len(values), 999):
+        many.observe_many(values[start:start + 999], ("q",))
+    many.observe_many([], ("q",))
+    many.observe_many([], ("empty",))
+    assert many._counts == one._counts
+    assert many._sums == one._sums
+    assert many._totals == one._totals
 
 
 # -- window rollup -----------------------------------------------------------
